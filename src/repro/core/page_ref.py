@@ -168,8 +168,9 @@ def point_page_refs_grid(
     lut = _point_lut_traced(
         eps_grid.astype(jnp.int32)[:, None, None], d_radius, c_ipp
     )                                                      # (K, 2D+1, C_ipp)
-    band = (lut.reshape(k * width, c_ipp) @ pos_hist.T).reshape(
-        k, width, num_pages)
+    band = jnp.matmul(lut.reshape(k * width, c_ipp), pos_hist.T,
+                      precision=jax.lax.Precision.HIGHEST).reshape(
+        k, width, num_pages)                               # f32 on TPU too
     out = jnp.zeros((k, num_pages + 2 * d_radius), jnp.float32)
     for j in range(width):                                 # shifted adds
         out = out.at[:, j:j + num_pages].add(band[:, j, :])

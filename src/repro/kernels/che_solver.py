@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 __all__ = ["che_sums", "che_solve"]
 
 _LANES = 128
@@ -71,7 +69,7 @@ def che_sums(probs, t_candidates, *, block_rows: int = 256,
         ],
         out_specs=pl.BlockSpec((1, k), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, k), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(p2, t2)

@@ -14,8 +14,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 __all__ = ["decode_attention"]
 
 _NEG_INF = -1e30
@@ -96,7 +94,7 @@ def decode_attention(q, k_cache, v_cache, lengths, *, block_kv: int = 512,
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(lens, qq, kk, vv)

@@ -9,7 +9,7 @@ bisection (or the LFU top-C mass) runs lockstep over the row's C
 capacities as (C, P) VPU work on the resident block, the policy-aware
 sorted-scan model and the mixed composition of
 ``cache_models.hit_rate_grid`` apply in place, and each program folds its
-row's objective minimum into a revisited (1, 1) accumulator tile with a
+row's objective minimum into a revisited SMEM scalar accumulator with a
 lowest-cell-id tie-break.  A (knob x split x capacity) table therefore
 prices in a single launch — one HBM pass over the histograms, no
 per-stage XLA round trips.
@@ -19,6 +19,14 @@ Semantics mirror ``cache_models.hit_rate_grid`` branch for branch
 below one page, thrash/frequency/compulsory sorted regimes, expected-miss
 composition); equivalence is float32-tolerance only (summation order),
 pinned by tests/test_engine.py against the host executor.
+
+On a TPU v5e the kernel compiles (tests/test_tpu_compile.py) with every
+row and cell resident in VMEM, so P is bounded.  With C <= 128 cells per
+row, every mode compiles at P <= ``V5E_MAX_PAGES`` = 32,768 pages; all
+but multi-policy launches with writes also at 65,536, and plain lru,
+fifo, lfu and multi at 131,072 (the largest tried).  Compile time grows
+with C * P (for multi, about 1.5 min at 65,536 and 3 min at 131,072).
+Past that the solve needs a P-tiled reduction.
 """
 from __future__ import annotations
 
@@ -27,10 +35,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
-__all__ = ["price_grid", "PAD_ID"]
+__all__ = ["price_grid", "PAD_ID", "V5E_MAX_PAGES"]
 
 _LANES = 128
 #: Cell id marking a padded (row, slot) cell; valid ids are always below it.
@@ -38,6 +45,28 @@ PAD_ID = 2**31 - 1
 
 _F32_COLS = 16   # packed per-row float32 scalars (see _price_kernel)
 _I32_COLS = 8    # packed per-row int32 scalars
+
+# scoped VMEM: half of v5e's 128 MiB; raising it further admits no larger P
+_VMEM_LIMIT_BYTES = 64 * 2**20
+#: Largest page count every mode compiles at on v5e (C <= 128 cells/row).
+V5E_MAX_PAGES = 32_768
+
+# |x| below which _expm1 sums its Taylor series: the degree-8 remainder is
+# under 1e-8 relative there, and above it exp(x) - 1 loses at most ~3 ulp
+_EXPM1_SERIES = 0.5
+
+
+def _expm1(x):
+    """``exp(x) - 1`` from ops Mosaic lowers, accurate for small ``|x|``.
+
+    Mosaic has no ``expm1``; plain ``exp(x) - 1`` cancels catastrophically
+    for the small ``p * t`` of cold pages, so small arguments take a
+    Horner-form Taylor series instead.
+    """
+    series = jnp.ones_like(x)
+    for n in range(8, 1, -1):
+        series = 1.0 + series * x / n
+    return jnp.where(jnp.abs(x) < _EXPM1_SERIES, x * series, jnp.exp(x) - 1.0)
 
 
 def _price_kernel(*refs, policy: str, has_sorted: bool, has_write: bool,
@@ -85,8 +114,8 @@ def _price_kernel(*refs, policy: str, has_sorted: bool, has_write: bool,
 
     @pl.when(i == 0)
     def _init():
-        bv_ref[...] = jnp.full_like(bv_ref, jnp.inf)
-        bi_ref[...] = jnp.full_like(bi_ref, jnp.int32(PAD_ID))
+        bv_ref[0, 0] = jnp.float32(jnp.inf)
+        bi_ref[0, 0] = jnp.int32(PAD_ID)
 
     sample_refs, full, n_f, pmin = f[0, 0], f[0, 1], f[0, 2], f[0, 3]
     n_i = z[0, 0]
@@ -101,12 +130,12 @@ def _price_kernel(*refs, policy: str, has_sorted: bool, has_write: bool,
 
         def occ(t):                                         # (C, 1) -> (C, P)
             if policy == "lru":
-                return -jnp.expm1(-p * t)
+                return -_expm1(-p * t)
             if policy == "fifo":
                 return p * t / (1.0 - p + p * t)
             # multi: per-program scalar select between the recency forms
             # (the bisected objective stays monotone either way)
-            return jnp.where(pol_id == 0, -jnp.expm1(-p * t),
+            return jnp.where(pol_id == 0, -_expm1(-p * t),
                              p * t / (1.0 - p + p * t))
 
         def body(_, st):
@@ -136,7 +165,7 @@ def _price_kernel(*refs, policy: str, has_sorted: bool, has_write: bool,
         w_mass = jnp.sum(w)
         if policy in ("lru", "fifo", "multi"):
             r = jnp.maximum(p - w, 0.0)
-            dirty = w + r * -jnp.expm1(-w * t_c)            # (C, P)
+            dirty = w + r * -_expm1(-w * t_c)               # (C, P)
             wb = jnp.sum((1.0 - occ(t_c)) * dirty, axis=1,
                          keepdims=True).T                   # (1, C)
         if lfu_read:
@@ -249,45 +278,48 @@ def price_grid(policy: str, probs, sorted_probs, cov_desc, f32s, i32s,
         ids = jnp.pad(ids, ((0, 0), (0, pad_c)), constant_values=PAD_ID)
     pp, cc = p_width + pad_p, c + pad_c
 
-    inputs, in_specs = [probs], [pl.BlockSpec((1, pp), lambda i: (i, 0))]
-    if policy in ("lfu", "multi"):
-        inputs.append(sorted_probs)
-        in_specs.append(pl.BlockSpec((1, pp), lambda i: (i, 0)))
-    if has_sorted and policy in ("lfu", "multi"):
-        inputs.append(cov_desc)
-        in_specs.append(pl.BlockSpec((1, pp), lambda i: (i, 0)))
-    if has_write:
-        inputs.append(wprobs)
-        in_specs.append(pl.BlockSpec((1, pp), lambda i: (i, 0)))
-        if policy in ("lfu", "multi"):
-            inputs.append(wprobs_q)
-            in_specs.append(pl.BlockSpec((1, pp), lambda i: (i, 0)))
-    inputs += [f32s, i32s, caps_f, caps_i, ids]
-    in_specs += [
-        pl.BlockSpec((1, _F32_COLS), lambda i: (i, 0)),
-        pl.BlockSpec((1, _I32_COLS), lambda i: (i, 0)),
-        pl.BlockSpec((1, cc), lambda i: (i, 0)),
-        pl.BlockSpec((1, cc), lambda i: (i, 0)),
-        pl.BlockSpec((1, cc), lambda i: (i, 0)),
-    ]
+    def row(x, width):
+        # (K, X) -> (K, 1, X) with the row axis squeezed out of the block:
+        # each program sees a (1, X) tile whose dims equal the array's own,
+        # which Mosaic accepts for any K
+        spec = pl.BlockSpec((None, 1, width), lambda i: (i, 0, 0))
+        return x.reshape(k, 1, width), spec
 
+    lanes = [(probs, pp)]
+    if policy in ("lfu", "multi"):
+        lanes.append((sorted_probs, pp))
+    if has_sorted and policy in ("lfu", "multi"):
+        lanes.append((cov_desc, pp))
+    if has_write:
+        lanes.append((wprobs, pp))
+        if policy in ("lfu", "multi"):
+            lanes.append((wprobs_q, pp))
+    lanes += [(f32s, _F32_COLS), (i32s, _I32_COLS), (caps_f, cc),
+              (caps_i, cc), (ids, cc)]
+    inputs, in_specs = zip(*(row(x, wd) for x, wd in lanes))
+
+    # the argmin accumulator is two scalars revisited by every program
+    acc_spec = pl.BlockSpec((1, 1), lambda i: (0, 0),
+                            memory_space=pltpu.SMEM)
     h, best_val, best_id = pl.pallas_call(
         functools.partial(_price_kernel, policy=policy,
                           has_sorted=has_sorted, has_write=has_write,
                           iters=iters, n_in=len(inputs)),
         grid=(k,),
-        in_specs=in_specs,
+        in_specs=list(in_specs),
         out_specs=[
-            pl.BlockSpec((1, cc), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
+            pl.BlockSpec((None, 1, cc), lambda i: (i, 0, 0)),
+            acc_spec,
+            acc_spec,
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((k, cc), jnp.float32),
+            jax.ShapeDtypeStruct((k, 1, cc), jnp.float32),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
             jax.ShapeDtypeStruct((1, 1), jnp.int32),
         ],
-        compiler_params=_CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(*inputs)
-    return h[:, :c], best_val, best_id
+    return h[:, 0, :c], best_val, best_id

@@ -42,7 +42,9 @@ candidate row x one page-tile block of the padded histogram and
 accumulates its query tiles into the revisited block (zero-initialized on
 the first visit), so VMEM stays bounded whatever the workload size.
 Interpret mode off-TPU via the shared ``kernels.ops._auto_interpret``
-rule.
+rule.  On a TPU v5e it compiles (tests/test_tpu_compile.py) at every P
+tried, up to 4M pages: a program's VMEM depends on the tiles and the
+stacked LUT width, not on P.
 """
 from __future__ import annotations
 
@@ -53,10 +55,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import page_ref
 from repro.kernels import ops as kernel_ops
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 __all__ = ["profile_grid", "point_page_refs_mixed_eps_grid"]
 
@@ -68,6 +70,24 @@ _P_TILE = 2048       # padded-histogram columns per program
 
 def _ceil_to(n: int, m: int) -> int:
     return -(-n // m) * m
+
+
+def _dot_onehot(x, onehot):
+    """``x @ onehot`` to f32 rounding, from the MXU's bf16 passes.
+
+    A TPU contracts f32 operands in one bf16 pass unless told otherwise,
+    which would round the Eq. 12 fractions to 8 bits (precision=HIGHEST
+    runs out of VMEM at real LUT widths).  ``onehot`` holds 0/1, exact in
+    bf16, so three bf16 parts of ``x`` (which sum to ``x`` exactly) give
+    exact products; only the f32 accumulation rounds.
+    """
+    out = None
+    for _ in range(3):
+        part = x.astype(jnp.bfloat16)
+        x = x - part.astype(jnp.float32)
+        prod = jnp.dot(part, onehot, preferred_element_type=jnp.float32)
+        out = prod if out is None else out + prod
+    return out
 
 
 def _occupancy_kernel(keys_ref, pages_ref, lut_ref, out_ref, *,
@@ -87,17 +107,16 @@ def _occupancy_kernel(keys_ref, pages_ref, lut_ref, out_ref, *,
     # one-hot over the combined (class, slot) key; pad queries (key -1)
     # match nothing, so their T1 column is zero and they contribute nothing
     sel = (jax.lax.broadcasted_iota(jnp.int32, (n_cc, q_tile), 0)
-           == keys).astype(jnp.float32)
-    t1 = jnp.dot(lut, sel, preferred_element_type=jnp.float32)  # (Wp, QT)
+           == keys).astype(jnp.bfloat16)
+    t1 = _dot_onehot(lut, sel)                              # (Wp, QT)
 
     page_col = pages.T                                      # (QT, 1)
     base = (jax.lax.broadcasted_iota(jnp.int32, (q_tile, p_tile), 1)
             + pt_i * p_tile)                                # global column
     acc = out_ref[...]
     for d in range(width):
-        oh = (base == page_col + d).astype(jnp.float32)     # (QT, PT)
-        acc = acc + jnp.dot(t1[d:d + 1, :], oh,
-                            preferred_element_type=jnp.float32)
+        oh = (base == page_col + d).astype(jnp.bfloat16)    # (QT, PT)
+        acc = acc + _dot_onehot(t1[d:d + 1, :], oh)
     out_ref[...] = acc
 
 
@@ -131,22 +150,25 @@ def profile_grid(keys, pages, lutstack, *, width: int, pad: int,
         pages = jnp.pad(pages, ((0, 0), (0, qp - q)), constant_values=-1)
     n_cc = int(lutstack.shape[1])
 
+    # rows ride a squeezed leading axis: each program sees (1, tile)
+    # blocks, which Mosaic accepts for any K
     out = pl.pallas_call(
         functools.partial(_occupancy_kernel, width=width, n_cc=n_cc,
                           q_tile=q_tile, p_tile=p_tile),
         grid=(k, pp // p_tile, qp // q_tile),
         in_specs=[
-            pl.BlockSpec((1, q_tile), lambda i, p, t: (i, t)),
+            pl.BlockSpec((None, 1, q_tile), lambda i, p, t: (i, 0, t)),
             pl.BlockSpec((1, q_tile), lambda i, p, t: (0, t)),
             pl.BlockSpec(lutstack.shape, lambda i, p, t: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, p_tile), lambda i, p, t: (i, p)),
-        out_shape=jax.ShapeDtypeStruct((k, pp), jnp.float32),
-        compiler_params=_CompilerParams(
+        out_specs=pl.BlockSpec((None, 1, p_tile),
+                               lambda i, p, t: (i, 0, p)),
+        out_shape=jax.ShapeDtypeStruct((k, 1, pp), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
-    )(keys, pages, lutstack)
-    return out[:, :pad]
+    )(keys.reshape(k, 1, qp), pages, lutstack)
+    return out[:, 0, :pad]
 
 
 def _lut_stack(class_eps, c_ipp: int, max_radius: int) -> np.ndarray:

@@ -7,11 +7,7 @@ state.  Single pod: 16x16 = 256 chips (v5e pod, 2D ICI torus).  Multi-pod:
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5 explicit-sharding API; older versions predate AxisType
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "HardwareSpec", "V5E"]
 
@@ -19,8 +15,6 @@ __all__ = ["make_production_mesh", "HardwareSpec", "V5E"]
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
