@@ -29,6 +29,7 @@ from typing import (Dict, NamedTuple, Optional, Protocol, Sequence, Tuple,
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import cache_models, dac, page_ref
 from repro.core.cam import CamEstimate, CamGeometry, capacity_pages
 from repro.core.workload import (INSERT, MIXED, POINT, RANGE, SORTED,
@@ -986,9 +987,11 @@ class CostSession:
                       else WriteStreamPart(_pad_row(wp.counts, width),
                                            wp.total_refs)
                       for wp in wparts]
+        with obs.span("profile.rows"):
+            counts = jnp.stack([jnp.asarray(r, jnp.float32) for r in rows])
         return GridProfiles(
             knobs=tuple(knobs),
-            counts=jnp.stack([jnp.asarray(r, jnp.float32) for r in rows]),
+            counts=counts,
             totals=np.asarray(totals, np.float64),
             dacs=np.asarray(dacs, np.float64),
             sizes=sizes_arr,
@@ -1028,16 +1031,17 @@ class CostSession:
             return {}
         geom = self.system.geom
         out, ok, eps_rows, ok_dacs = {}, [], [], []
-        for c in batchable:
-            try:
-                eps_q, e_dac = c.index.point_ref_eps(wl, geom)
-            except UnsupportedWorkloadError as e:
-                skipped.append(SkippedCandidate(c.knob, str(e)))
-                out[id(c)] = None
-                continue
-            ok.append(c)
-            eps_rows.append(np.asarray(eps_q, np.int64))
-            ok_dacs.append(float(e_dac))
+        with obs.span("profile.route"):
+            for c in batchable:
+                try:
+                    eps_q, e_dac = c.index.point_ref_eps(wl, geom)
+                except UnsupportedWorkloadError as e:
+                    skipped.append(SkippedCandidate(c.knob, str(e)))
+                    out[id(c)] = None
+                    continue
+                ok.append(c)
+                eps_rows.append(np.asarray(eps_q, np.int64))
+                ok_dacs.append(float(e_dac))
         if ok:
             num_pages = geom.num_pages(int(ok[0].index.n))
             if _resolve_profile_executor(executor) == "device":
@@ -1049,8 +1053,10 @@ class CostSession:
             else:
                 counts_b, totals_b = page_ref.point_page_refs_mixed_eps_grid(
                     wl.positions, np.stack(eps_rows), geom.c_ipp, num_pages)
-            for i, c in enumerate(ok):
-                out[id(c)] = (counts_b[i], float(totals_b[i]), ok_dacs[i])
+            with obs.span("profile.rows"):
+                for i, c in enumerate(ok):
+                    out[id(c)] = (counts_b[i], float(totals_b[i]),
+                                  ok_dacs[i])
         return out
 
     def _sorted_grid(self, feasible, skipped, wl: Workload,

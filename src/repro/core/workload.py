@@ -19,6 +19,8 @@ from typing import Optional, Tuple
 
 import numpy as np
 
+from repro import obs
+
 __all__ = ["Workload", "locate", "subsample_indices"]
 
 POINT = "point"
@@ -36,6 +38,7 @@ WRITE_KINDS = (INSERT, UPDATE, DELETE)
 _KINDS = (POINT, RANGE, SORTED, MIXED) + WRITE_KINDS
 
 
+@obs.span("workload.locate")
 def locate(keys: np.ndarray, query_keys: np.ndarray) -> np.ndarray:
     """True ranks of ``query_keys`` in the sorted key file (LocateQueries).
 

@@ -46,6 +46,8 @@ from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
+
 __all__ = ["PriceTable", "PriceSolution", "PricingEngine"]
 
 
@@ -328,6 +330,7 @@ class PricingEngine:
         return self._instances[executor]
 
     # ---------------------------------------------------------------- price
+    @obs.span("engine.price")
     def price(self, table: PriceTable, *, objective: str = "io",
               executor=None) -> PriceSolution:
         """Solve every cell of ``table`` and rank by ``objective``.

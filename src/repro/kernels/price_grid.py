@@ -321,5 +321,6 @@ def price_grid(policy: str, probs, sorted_probs, cov_desc, f32s, i32s,
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
+        name="price_grid",
     )(*inputs)
     return h[:, 0, :c], best_val, best_id

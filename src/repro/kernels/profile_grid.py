@@ -43,8 +43,17 @@ accumulates its query tiles into the revisited block (zero-initialized on
 the first visit), so VMEM stays bounded whatever the workload size.
 Interpret mode off-TPU via the shared ``kernels.ops._auto_interpret``
 rule.  On a TPU v5e it compiles (tests/test_tpu_compile.py) at every P
-tried, up to 4M pages: a program's VMEM depends on the tiles and the
-stacked LUT width, not on P.
+tried, up to 4M pages: a program's VMEM depends on the tiles, the band
+width and the stacked LUT width, not on P.  The band loop is unrolled and
+no ``vmem_limit_bytes`` is set, so wide bands do overflow v5e's 16 MiB of
+scoped VMEM: RMI branches 64..512 over 8M books keys quantize their leaf
+errors to 4,096, a 65-page band, and at 10-13 eps classes need
+16.7-17.0 MB (refused on the chip and by the v5e compiler); 33-page bands
+compile.
+
+Host work per call, each step a program span (``repro.obs``):
+``profile.prep`` (class codes, dense rank, the LUT stack, the transfers
+and the launch) and ``profile.wait`` (the first host read, the totals).
 """
 from __future__ import annotations
 
@@ -57,6 +66,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import obs
 from repro.core import page_ref
 from repro.kernels import ops as kernel_ops
 
@@ -167,6 +177,7 @@ def profile_grid(keys, pages, lutstack, *, width: int, pad: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
+        name="profile_grid",
     )(keys.reshape(k, 1, qp), pages, lutstack)
     return out[:, 0, :pad]
 
@@ -212,29 +223,34 @@ def point_page_refs_mixed_eps_grid(
     Returns (counts (K, num_pages) float32 device array, totals (K,)
     float64 host array) — shapes and meaning identical to the host kernel.
     """
-    positions = np.asarray(positions, np.int64)
-    eps_rows = np.maximum(np.asarray(eps_rows, np.int64), 1)
-    k, q_n = eps_rows.shape
-    if positions.shape[0] != q_n:
-        raise ValueError(f"eps_rows has {q_n} columns for "
-                         f"{positions.shape[0]} positions")
-    page = (positions // c_ipp).astype(np.int32)
-    slot = (positions - page.astype(np.int64) * c_ipp).astype(np.int32)
-    max_radius = page_ref.lut_radius(int(eps_rows.max()), c_ipp)
-    pad = num_pages + 2 * max_radius
+    with obs.span("profile.prep"):
+        positions = np.asarray(positions, np.int64)
+        eps_rows = np.maximum(np.asarray(eps_rows, np.int64), 1)
+        k, q_n = eps_rows.shape
+        if positions.shape[0] != q_n:
+            raise ValueError(f"eps_rows has {q_n} columns for "
+                             f"{positions.shape[0]} positions")
+        page = (positions // c_ipp).astype(np.int32)
+        slot = (positions - page.astype(np.int64) * c_ipp).astype(np.int32)
+        max_radius = page_ref.lut_radius(int(eps_rows.max()), c_ipp)
+        pad = num_pages + 2 * max_radius
 
-    codes, classes = page_ref.mixed_eps_class_codes(eps_rows.ravel())
-    present = np.flatnonzero(np.bincount(codes))
-    class_eps = [page_ref.mixed_eps_class_eps(c, classes) for c in present]
-    # dense-rank the (possibly sparse) codes into lutstack column groups
-    dense = np.searchsorted(present, codes.astype(np.int64)).astype(np.int32)
-    keys = dense.reshape(k, q_n) * np.int32(c_ipp) + slot[None, :]
+        codes, classes = page_ref.mixed_eps_class_codes(eps_rows.ravel())
+        present = np.flatnonzero(np.bincount(codes))
+        class_eps = [page_ref.mixed_eps_class_eps(c, classes)
+                     for c in present]
+        # dense-rank the (possibly sparse) codes into lutstack column groups
+        dense = np.searchsorted(present,
+                                codes.astype(np.int64)).astype(np.int32)
+        keys = dense.reshape(k, q_n) * np.int32(c_ipp) + slot[None, :]
 
-    padded = profile_grid(
-        jnp.asarray(keys), jnp.asarray(page[None, :]),
-        jnp.asarray(_lut_stack(class_eps, c_ipp, max_radius)),
-        width=2 * max_radius + 1, pad=pad,
-        interpret=kernel_ops._auto_interpret(interpret))
-    counts = padded[:, max_radius:max_radius + num_pages]
-    totals = np.asarray(jnp.sum(counts, axis=1), np.float64)
+        padded = profile_grid(
+            jnp.asarray(keys), jnp.asarray(page[None, :]),
+            jnp.asarray(_lut_stack(class_eps, c_ipp, max_radius)),
+            width=2 * max_radius + 1, pad=pad,
+            interpret=kernel_ops._auto_interpret(interpret))
+        counts = padded[:, max_radius:max_radius + num_pages]
+        totals = jnp.sum(counts, axis=1)
+    with obs.span("profile.wait"):
+        totals = obs.to_host(totals, np.float64)
     return counts, totals

@@ -44,6 +44,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.core.workload import Workload
 from repro.serving.sketch import DEFAULT_PAGE_BINS, WindowSketch, tv_distance
 from repro.serving.trace import TraceEvent, compile_events, iter_batches
@@ -161,16 +162,21 @@ class ServingSession:
         """
         for batch in iter_batches(warmup_events, self.config.batch_size):
             self.sketch.update(compile_events(batch, self.keys))
-        result = self._retune()
+        with obs.span("serving.retune"):
+            result = self._retune()
         self._deploy(result)
         return result
 
     # ---------------------------------------------------------------- ingest
     def observe(self, events: Sequence[TraceEvent]) -> List[BatchReport]:
-        """Batch an event stream through :meth:`ingest`."""
-        return [self.ingest(compile_events(batch, self.keys),
-                            ts=batch[-1].ts)
-                for batch in iter_batches(events, self.config.batch_size)]
+        """Batch an event stream through :meth:`ingest`; each batch is one
+        ``serving.observe`` span, the root of its program spans."""
+        reports = []
+        for batch in iter_batches(events, self.config.batch_size):
+            with obs.span("serving.observe"):
+                reports.append(self.ingest(compile_events(batch, self.keys),
+                                           ts=batch[-1].ts))
+        return reports
 
     def ingest(self, workload: Workload, ts: float = 0.0) -> BatchReport:
         """One loop iteration: sketch update, drift check, maybe a retune."""
@@ -181,13 +187,14 @@ class ServingSession:
         self.sketch.update(workload)
         self.stats.batches += 1
         self.stats.events += workload.n_queries
-        if self._cooldown > 0:
-            self._cooldown -= 1
-        tv = tv_distance(self.sketch.summary(), self._baseline)
-        if not self._armed and tv < cfg.drift_threshold - cfg.hysteresis:
-            self._armed = True
-        drifted = tv > cfg.drift_threshold and (
-            self._armed or tv > self._last_eval_tv + cfg.hysteresis)
+        with obs.span("serving.detect"):
+            if self._cooldown > 0:
+                self._cooldown -= 1
+            tv = tv_distance(self.sketch.summary(), self._baseline)
+            if not self._armed and tv < cfg.drift_threshold - cfg.hysteresis:
+                self._armed = True
+            drifted = tv > cfg.drift_threshold and (
+                self._armed or tv > self._last_eval_tv + cfg.hysteresis)
         decision = None
         if drifted and self._cooldown == 0:
             self.stats.drift_events += 1
@@ -207,6 +214,7 @@ class ServingSession:
         self._last_eval_tv = 0.0
         self._cooldown = self.config.cooldown_batches
 
+    @obs.span("serving.retune")
     def _evaluate(self, tv: float, ts: float) -> RetuneDecision:
         cfg = self.config
         result = self._retune()

@@ -43,6 +43,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.session import (CostSession, GridCandidate, GridProfiles,
                                 SortedScanPart, WriteStreamPart)
 from repro.core.workload import (DELETE, INSERT, MIXED, POINT, RANGE, SORTED,
@@ -282,6 +283,7 @@ class WindowSketch:
         self.events_ingested = 0
 
     # ---------------------------------------------------------------- update
+    @obs.span("sketch.update")
     def update(self, workload: Workload) -> SketchChunk:
         """Ingest one batch: profile it, append its chunk, evict the oldest.
 
@@ -303,6 +305,7 @@ class WindowSketch:
         self.events_ingested += chunk.n_queries
         return chunk
 
+    @obs.span("sketch.chunk")
     def _chunk_from(self, profs: GridProfiles,
                     workload: Workload) -> SketchChunk:
         geom = self.system.geom
@@ -311,7 +314,7 @@ class WindowSketch:
             workload, num_pages, geom.c_ipp, self.page_bins)
         chunk = SketchChunk(
             n_queries=int(profs.n_queries),
-            counts=np.asarray(profs.counts, np.float64),
+            counts=obs.to_host(profs.counts, np.float64),
             totals=np.asarray(profs.totals, np.float64),
             dac_mass=np.asarray(profs.dacs, np.float64) * profs.n_queries,
             page_pop=page_pop, width_hist=width_hist, op_mix=op_mix)
@@ -320,7 +323,7 @@ class WindowSketch:
             # per-candidate (K, P) expected-write histograms and masses
             zero_w = np.zeros(num_pages, np.float64)
             chunk.write_counts = np.stack(
-                [np.asarray(wp.counts, np.float64) if wp is not None
+                [obs.to_host(wp.counts, np.float64) if wp is not None
                  else zero_w for wp in profs.wparts])
             chunk.write_refs = np.asarray(
                 [wp.total_refs if wp is not None else 0.0
@@ -329,7 +332,7 @@ class WindowSketch:
         if spart is not None:
             chunk.sorted_refs = float(spart.total_refs)
             chunk.sorted_pinned = float(spart.pinned_retouches)
-            chunk.sorted_coverage = np.asarray(spart.coverage, np.float64)
+            chunk.sorted_coverage = obs.to_host(spart.coverage, np.float64)
             chunk.sorted_min_caps = np.asarray(
                 [sp.min_capacity if sp is not None else 1
                  for sp in profs.sparts], np.int64)
@@ -353,6 +356,7 @@ class WindowSketch:
             raise ValueError("empty sketch: ingest at least one batch first")
         return reduce(merge_accums, map(_Accum.lift, self.chunks))
 
+    @obs.span("sketch.merge")
     def to_profiles(self) -> GridProfiles:
         """The live window as a :class:`GridProfiles` — NO replay.
 

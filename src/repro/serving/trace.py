@@ -27,6 +27,7 @@ from typing import Iterable, Iterator, List, Optional, Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.core.workload import Workload, locate
 
 __all__ = ["TraceEvent", "parse_jsonl", "to_jsonl", "iter_batches",
@@ -125,6 +126,7 @@ def iter_batches(events: Iterable[TraceEvent],
         yield batch
 
 
+@obs.span("trace.compile")
 def compile_events(events: Sequence[TraceEvent],
                    keys: np.ndarray) -> Workload:
     """Compile one event batch into a Workload against ``keys``.
@@ -145,29 +147,34 @@ def compile_events(events: Sequence[TraceEvent],
         raise ValueError("cannot compile an empty event batch")
     keys = np.asarray(keys)
     n = int(keys.shape[0])
-    point_keys = [e.key for e in events if e.op == POINT]
-    range_bounds = [(e.lo_key, e.hi_key) for e in events if e.op == RANGE]
-    sorted_bounds = [(e.lo_key, e.hi_key) for e in events if e.op == SORTED]
+    with obs.span("trace.unpack"):
+        point_keys = np.asarray([e.key for e in events if e.op == POINT])
+        range_bounds = np.asarray([(e.lo_key, e.hi_key) for e in events
+                                   if e.op == RANGE])
+        sorted_bounds = np.asarray([(e.lo_key, e.hi_key) for e in events
+                                    if e.op == SORTED])
+        write_keys = [(build, np.asarray([e.key for e in events
+                                          if e.op == op]))
+                      for op, build in ((INSERT, Workload.insert),
+                                        (UPDATE, Workload.update),
+                                        (DELETE, Workload.delete))]
 
     parts = []
-    if point_keys:
-        qk = np.asarray(point_keys)
-        parts.append(Workload.point(locate(keys, qk), n=n, query_keys=qk))
-    if range_bounds:
-        lo, hi = np.asarray(range_bounds).T
+    if point_keys.size:
+        parts.append(Workload.point(locate(keys, point_keys), n=n,
+                                    query_keys=point_keys))
+    if range_bounds.size:
+        lo, hi = range_bounds.T
         lo_pos = locate(keys, lo)
         hi_pos = np.maximum(locate(keys, hi), lo_pos)
         parts.append(Workload.range_scan(lo_pos, hi_pos, n=n))
-    if sorted_bounds:
-        lo, hi = np.asarray(sorted_bounds).T
+    if sorted_bounds.size:
+        lo, hi = sorted_bounds.T
         lo_pos = locate(keys, lo)
         hi_pos = np.maximum(locate(keys, hi), lo_pos)
         parts.append(Workload.sorted_stream(lo_pos, hi_pos, n=n))
-    for op, build in ((INSERT, Workload.insert), (UPDATE, Workload.update),
-                      (DELETE, Workload.delete)):
-        wkeys = [e.key for e in events if e.op == op]
-        if wkeys:
-            qk = np.asarray(wkeys)
+    for build, qk in write_keys:
+        if qk.size:
             parts.append(build(locate(keys, qk), n=n, query_keys=qk))
     return parts[0] if len(parts) == 1 else Workload.mixed(*parts)
 

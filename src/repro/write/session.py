@@ -24,6 +24,7 @@ from typing import List, Optional, Sequence, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.session import CostSession, GridCandidate, GridProfiles, System
 from repro.core.workload import MIXED, WRITE_KINDS, Workload
 from repro.engine.table import PriceTable, PricingEngine
@@ -173,6 +174,7 @@ class WriteSession:
             burst, executor=self.config.profile_executor)
         return profs, burst.n_queries
 
+    @obs.span("write.price_event")
     def _price_event(self) -> Tuple[float, float, float]:
         """ONE engine call: (io_defer, io_merged, merge_io_total)."""
         read_profs = self.sketch.to_profiles()
@@ -192,41 +194,32 @@ class WriteSession:
                     if self.delta.entries else float("inf"))
         return io_defer, io_merged, merge_io
 
-    # -------------------------------------------------------------------- run
-    def run(self, events: Sequence[TraceEvent]) -> WriteSessionReport:
-        records: List[BatchRecord] = []
-        read_io_total = 0.0
-        merge_io_total = 0.0
-        for i, batch in enumerate(iter_batches(events,
-                                               self.config.batch_size)):
-            wl = compile_events(batch, self.keys)
-            reads, writes = split_reads_writes(wl)
-            n_reads = reads.n_queries if reads is not None else 0
-            n_writes = writes.n_queries if writes is not None else 0
-            if reads is not None:
-                self.sketch.update(reads)
-            if writes is not None:
-                self.delta.stage(writes)
-            if len(self.sketch) == 0:
-                # nothing priceable yet (pure-write prefix): stage and wait
-                records.append(BatchRecord(i, n_reads, n_writes,
-                                           self.delta.entries,
-                                           self._capacity_now(),
-                                           self.cap_empty, 0.0, 0.0,
-                                           float("inf"), 0.0, False,
-                                           "no_reads_yet"))
-                continue
+    @obs.span("write.batch")
+    def _batch(self, i: int, batch: Sequence[TraceEvent]) -> BatchRecord:
+        """One batch: compile, stage, price, decide; its ledger row."""
+        wl = compile_events(batch, self.keys)
+        reads, writes = split_reads_writes(wl)
+        n_reads = reads.n_queries if reads is not None else 0
+        n_writes = writes.n_queries if writes is not None else 0
+        if reads is not None:
+            self.sketch.update(reads)
+        if writes is not None:
+            self.delta.stage(writes)
+        if len(self.sketch) == 0:
+            # nothing priceable yet (pure-write prefix): stage and wait
+            return BatchRecord(i, n_reads, n_writes, self.delta.entries,
+                               self._capacity_now(), self.cap_empty, 0.0,
+                               0.0, float("inf"), 0.0, False, "no_reads_yet")
 
-            io_defer, io_merged, merge_io = self._price_event()
-            batch_read_io = io_defer * n_reads
-            read_io_total += batch_read_io
-            # ledger the state the DECISION saw (pre-flush)
-            cap_now, delta_entries = self._capacity_now(), self.delta.entries
+        io_defer, io_merged, merge_io = self._price_event()
+        # ledger the state the DECISION saw (pre-flush)
+        cap_now, delta_entries = self._capacity_now(), self.delta.entries
 
-            # only reads pay io_defer, so the horizon counts expected reads;
-            # the CURRENT batch's read rate predicts the coming regime far
-            # better than a lifetime mean on piecewise-stationary traffic
-            # (the lagging mean stalls big post-burst flushes for batches)
+        # only reads pay io_defer, so the horizon counts expected reads;
+        # the CURRENT batch's read rate predicts the coming regime far
+        # better than a lifetime mean on piecewise-stationary traffic
+        # (the lagging mean stalls big post-burst flushes for batches)
+        with obs.span("write.decide"):
             horizon = self.config.horizon_batches * n_reads
             decision: MergeDecision = self.scheduler.decide(DecisionContext(
                 batch_index=i, io_defer=io_defer, io_merged=io_merged,
@@ -236,16 +229,28 @@ class WriteSession:
                 batches_since_merge=self.batches_since_merge))
             merged = bool(decision.merge and self.delta.entries)
             if merged:
-                merge_io_total += merge_io
                 self.delta.clear()
                 self.batches_since_merge = 0
             else:
                 self.batches_since_merge += 1
-            records.append(BatchRecord(
-                i, n_reads, n_writes, delta_entries,
-                cap_now, self.cap_empty, io_defer, io_merged,
-                merge_io if merge_io != float("inf") else 0.0,
-                batch_read_io, merged, decision.reason))
+        return BatchRecord(
+            i, n_reads, n_writes, delta_entries,
+            cap_now, self.cap_empty, io_defer, io_merged,
+            merge_io if merge_io != float("inf") else 0.0,
+            io_defer * n_reads, merged, decision.reason)
+
+    # -------------------------------------------------------------------- run
+    def run(self, events: Sequence[TraceEvent]) -> WriteSessionReport:
+        records: List[BatchRecord] = []
+        read_io_total = 0.0
+        merge_io_total = 0.0
+        for i, batch in enumerate(iter_batches(events,
+                                               self.config.batch_size)):
+            record = self._batch(i, batch)
+            records.append(record)
+            read_io_total += record.read_io
+            if record.merged:
+                merge_io_total += record.merge_io
         return WriteSessionReport(
             scheduler=getattr(self.scheduler, "name",
                               type(self.scheduler).__name__),
