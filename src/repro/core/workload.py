@@ -38,15 +38,63 @@ WRITE_KINDS = (INSERT, UPDATE, DELETE)
 _KINDS = (POINT, RANGE, SORTED, MIXED) + WRITE_KINDS
 
 
+def _as_key_dtype(query_keys: np.ndarray, dtype: np.dtype):
+    """``query_keys`` in the key file's ``dtype`` with every left-rank
+    (clamped to ``n - 1``) kept, or None where no such cast is known.
+
+    Integer needles into integer keys clip to the key dtype's range: below
+    it the rank is 0 either way, above it ``n`` (``n - 1`` once clamped).
+    Float needles into integer keys take their ceiling first, since for an
+    integer ``k``, ``k < q`` exactly when ``k < ceil(q)``; NaN has no such
+    integer.
+    """
+    q = query_keys
+    if q.dtype == dtype:
+        return q
+    if dtype.kind not in "iu":
+        return None
+    info = np.iinfo(dtype)
+    if q.dtype.kind in "iu":
+        qi = np.iinfo(q.dtype)
+        if qi.min < info.min:
+            q = np.maximum(q, np.asarray(info.min, q.dtype))
+        if qi.max > info.max:
+            q = np.minimum(q, np.asarray(info.max, q.dtype))
+        return q.astype(dtype)
+    if q.dtype.kind != "f" or np.isnan(q).any():
+        return None
+    c = np.maximum(np.ceil(q), info.min)     # info.min is a float exactly
+    above = c >= 2.0 ** (info.bits - (info.min < 0))   # > info.max
+    out = np.where(above, 0, c).astype(dtype)
+    out[above] = info.max
+    return out
+
+
 @obs.span("workload.locate")
 def locate(keys: np.ndarray, query_keys: np.ndarray) -> np.ndarray:
     """True ranks of ``query_keys`` in the sorted key file (LocateQueries).
 
     Computed ONCE per (dataset, workload) pair; every estimation call reuses
     the cached result — this is where CAM's tuning-loop speedup starts.
+    The search runs in the key file's own dtype wherever the needles cast
+    to it without moving a rank (integer or non-NaN float needles into
+    integer keys), so the key file is never copied and integer keys above
+    2**53 rank exactly; other pairs search in their common type.  Needles
+    out of order are searched in ascending order and their ranks put back.
     """
     keys = np.asarray(keys)
-    pos = np.searchsorted(keys, np.asarray(query_keys), side="left")
+    query_keys = np.asarray(query_keys)
+    q = _as_key_dtype(query_keys, keys.dtype)
+    if q is None:
+        pos = np.searchsorted(keys, query_keys, side="left")
+    else:
+        obs.count("locate.native")
+        if q.ndim == 1 and np.any(q[1:] < q[:-1]):
+            order = np.argsort(q, kind="stable")
+            pos = np.empty(q.shape, np.intp)
+            pos[order] = np.searchsorted(keys, q[order], side="left")
+        else:
+            pos = np.searchsorted(keys, q, side="left")
     return np.minimum(pos, keys.shape[0] - 1).astype(np.int64)
 
 
