@@ -14,6 +14,12 @@ estimator jits.
 * Sorted (join)  — Theorem III.1 needs only (R, N); computed from interval
   unions with a cummax, no histogram required.
 * RMI            — per-leaf mixture: grouped by distinct leaf error bound.
+
+Shape-stable batches: a served batch's part sizes change every batch, and a
+jitted estimator compiles once per input shape.  :func:`pad_to_bucket`
+pads a part's lanes to a power of two (:func:`bucket_lanes`) and the
+estimators that take ``n_valid`` give the padded lanes zero weight, so a
+serving loop compiles once per bucket and not once per batch.
 """
 from __future__ import annotations
 
@@ -24,7 +30,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
+
 __all__ = [
+    "bucket_lanes",
+    "pad_to_bucket",
     "point_lut",
     "point_page_refs",
     "point_page_refs_grid",
@@ -37,8 +47,36 @@ __all__ = [
     "page_intervals",
     "sorted_workload_rn",
     "sorted_workload_stats",
+    "sorted_window_stats",
     "point_access_prob_exact",
 ]
+
+
+#: The smallest lane bucket: tiny parts share one compiled shape.
+MIN_BUCKET = 256
+
+
+def bucket_lanes(n: int) -> int:
+    """Lanes a part of ``n`` references is padded to: the next power of
+    two, at least :data:`MIN_BUCKET`."""
+    return max(MIN_BUCKET, 1 << max(int(n) - 1, 0).bit_length())
+
+
+def pad_to_bucket(*arrays) -> Tuple[Tuple[np.ndarray, ...], np.ndarray]:
+    """Equal-length position arrays as int32, zero-padded to
+    :func:`bucket_lanes` of their length, and the count of real lanes as an
+    int32 scalar (a traced argument, so it never recompiles).  Counts
+    ``profile.lanes`` and ``profile.pad_lanes``."""
+    n = int(np.asarray(arrays[0]).shape[0])
+    lanes = bucket_lanes(n)
+    padded = []
+    for a in arrays:
+        out = np.zeros(lanes, np.int32)
+        out[:n] = np.asarray(a).reshape(-1)
+        padded.append(out)
+    obs.count("profile.lanes", n)
+    obs.count("profile.pad_lanes", lanes - n)
+    return tuple(padded), np.int32(n)
 
 
 def lut_radius(eps: int, c_ipp: int) -> int:
@@ -133,6 +171,7 @@ def point_page_refs_grid(
     d_radius: int,
     c_ipp: int,
     num_pages: int,
+    n_valid: jnp.ndarray,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Eq. 13 histograms for a WHOLE eps grid in one compiled pass.
 
@@ -152,6 +191,8 @@ def point_page_refs_grid(
       positions: (Q,) true ranks, shared page-ref state for the grid.
       eps_grid:  (K,) int32 candidate error bounds.
       d_radius:  static padded radius — ``lut_radius(max(eps_grid), c_ipp)``.
+      n_valid:   only the first ``n_valid`` positions count (the rest are
+                 :func:`pad_to_bucket` lanes).
 
     Returns:
       counts: (K, num_pages) expected reference histograms (boundary-clipped,
@@ -160,8 +201,9 @@ def point_page_refs_grid(
     """
     k = eps_grid.shape[0]
     width = 2 * d_radius + 1
+    weight = (jnp.arange(positions.shape[0]) < n_valid).astype(jnp.float32)
     pos_hist = jax.ops.segment_sum(
-        jnp.ones(positions.shape[0], jnp.float32),
+        weight,
         positions.astype(jnp.int32),
         num_segments=num_pages * c_ipp,
     ).reshape(num_pages, c_ipp)                            # shared state
@@ -430,6 +472,40 @@ def sorted_workload_rn(
     return r_total, n_distinct
 
 
+def _interval_stats(lo, hi, valid, num_pages: int):
+    """(R, N, coverage, pinned_retouches, widest) of the page intervals
+    ``valid`` marks; the other lanes add nothing."""
+    w = valid.astype(jnp.float32)
+    diff = jax.ops.segment_sum(w, lo, num_segments=num_pages + 1)
+    diff = diff - jax.ops.segment_sum(w, hi + 1, num_segments=num_pages + 1)
+    coverage = jnp.cumsum(diff)[:num_pages]
+    widths = jnp.where(valid, hi - lo + 1, 0)
+    r_total = jnp.sum(widths.astype(jnp.float32))
+    n_distinct = jnp.sum(coverage > 0).astype(jnp.float32)
+    pinned = jnp.sum(((lo[1:] == hi[:-1]) & valid[1:]).astype(jnp.float32))
+    return r_total, n_distinct, coverage, pinned, jnp.max(widths, initial=0)
+
+
+@functools.partial(jax.jit, static_argnames=("c_ipp", "num_pages"))
+def sorted_window_stats(
+    window_lo: jnp.ndarray, window_hi: jnp.ndarray, n_valid: jnp.ndarray,
+    c_ipp: int, num_pages: int
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """:func:`page_intervals` then :func:`sorted_workload_stats` of the
+    first ``n_valid`` windows of bucket-padded ones, in one compiled pass.
+
+    Returns ``(stats, coverage)``: ``stats`` is float32 (R, N,
+    pinned_retouches, widest window in pages), one host read; padded lanes
+    add nothing to any of them.
+    """
+    lo, hi = page_intervals(window_lo, window_hi, c_ipp, num_pages)
+    r_total, n_distinct, coverage, pinned, widest = _interval_stats(
+        lo, hi, jnp.arange(lo.shape[0]) < n_valid, num_pages)
+    stats = jnp.stack([r_total, n_distinct, pinned,
+                       widest.astype(jnp.float32)])
+    return stats, coverage
+
+
 def sorted_workload_stats(
     page_lo: jnp.ndarray, page_hi: jnp.ndarray, num_pages: int
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
@@ -438,7 +514,8 @@ def sorted_workload_stats(
     Deliberately NOT jitted: the join planner calls it with
     outer-relation-sized arrays whose shapes vary call to call, and a
     per-shape retrace would cost more than the handful of eager ops here
-    (one scatter, one scan, two reductions).
+    (one scatter, one scan, two reductions).  Callers that profile every
+    batch use :func:`sorted_window_stats` on bucket-padded windows.
 
     Extends :func:`sorted_workload_rn` with the two statistics the
     frequency-aware sorted-scan model (``cache_models.sorted_scan_*``)
@@ -461,11 +538,6 @@ def sorted_workload_stats(
     """
     lo = jnp.asarray(page_lo, jnp.int32)
     hi = jnp.asarray(page_hi, jnp.int32)
-    ones = jnp.ones(lo.shape[0], jnp.float32)
-    diff = jax.ops.segment_sum(ones, lo, num_segments=num_pages + 1)
-    diff = diff - jax.ops.segment_sum(ones, hi + 1, num_segments=num_pages + 1)
-    coverage = jnp.cumsum(diff)[:num_pages]
-    r_total = jnp.sum((hi - lo + 1).astype(jnp.float32))
-    n_distinct = jnp.sum(coverage > 0).astype(jnp.float32)
-    pinned = jnp.sum((lo[1:] == hi[:-1]).astype(jnp.float32))
+    r_total, n_distinct, coverage, pinned, _ = _interval_stats(
+        lo, hi, jnp.ones(lo.shape[0], bool), num_pages)
     return r_total, n_distinct, coverage, pinned

@@ -21,6 +21,7 @@ per-eps recompiles, which is the tuning-loop speedup the paper's §V needs.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 import time
 from typing import (Dict, NamedTuple, Optional, Protocol, Sequence, Tuple,
@@ -255,18 +256,19 @@ def sorted_part_for(workload: Workload, eps: int, geom: CamGeometry,
 
     The Theorem III.1 capacity premise comes from ``eps`` for uniformly
     error-bounded designs; with ``eps=0`` (no uniform bound, e.g. RMI) it is
-    read off the widest observed probe window instead.
+    read off the widest observed probe window instead.  The windows are
+    padded to a lane bucket, so streams of any length share a few compiled
+    shapes.
     """
-    plo, phi = page_ref.page_intervals(
-        jnp.asarray(workload.positions, jnp.int32),
-        jnp.asarray(workload.hi_positions, jnp.int32),
-        geom.c_ipp, num_pages)
-    r_total, n_distinct, coverage, pinned = page_ref.sorted_workload_stats(
-        plo, phi, num_pages)
+    (lo, hi), n_valid = page_ref.pad_to_bucket(workload.positions,
+                                               workload.hi_positions)
+    stats, coverage = page_ref.sorted_window_stats(lo, hi, n_valid,
+                                                   geom.c_ipp, num_pages)
+    r_total, n_distinct, pinned, widest = obs.to_host(stats, np.float64)
     if eps > 0:
         min_cap = 1 + int(np.ceil(2 * eps / geom.c_ipp))
     elif workload.n_queries:
-        min_cap = int(jnp.max(phi - plo + 1))
+        min_cap = int(widest)
     else:
         min_cap = 1
     return SortedScanPart(
@@ -286,6 +288,17 @@ def sorted_stream_profile(workload: Workload, geom: CamGeometry,
         expected_dac=sp.total_refs / max(workload.n_queries, 1),
         sorted_stream=True, distinct_pages=sp.distinct_pages,
         min_capacity=sp.min_capacity, sorted_part=sp)
+
+
+@functools.lru_cache(maxsize=256)
+def _uniform_dac(eps: Tuple[int, ...], c_ipp: int,
+                 strategy: str) -> np.ndarray:
+    """E[DAC] per query of each uniform bound (Lemmas III.2/III.3), float64
+    and read-only: a served grid asks for the same bounds every batch."""
+    out = np.asarray(dac.expected_dac(np.asarray(eps, np.float64), c_ipp,
+                                      strategy), np.float64)
+    out.flags.writeable = False
+    return out
 
 
 def _compulsory_coverage(sp: SortedScanPart, num_pages: int) -> jnp.ndarray:
@@ -892,10 +905,10 @@ class CostSession:
 
         rows, totals, dacs, knobs, sparts, sizes = [], [], [], [], [], []
         wparts = []
+        block = None        # the uniform grid's (K, P) rows, kept whole
         if uniform:
-            counts_u, totals_u, dacs_u, spart_u, wpart_u = self._uniform_grid(
+            block, totals_u, dacs_u, spart_u, wpart_u = self._uniform_grid(
                 uniform, wl)
-            rows.extend(counts_u)
             totals.extend(totals_u)
             dacs.extend(dacs_u)
             knobs.extend(c.knob for c in uniform)
@@ -970,6 +983,8 @@ class CostSession:
                        + "; ".join(s.reason for s in skipped) + ")")
 
         sizes_arr = np.asarray(sizes, np.float64)
+        if block is not None and rows:
+            rows = list(block) + rows
         widths = [int(jnp.asarray(r).shape[0]) for r in rows]
         if len(set(widths)) > 1:
             # Index-backed candidates may live in per-knob SLOT spaces
@@ -988,7 +1003,11 @@ class CostSession:
                                            wp.total_refs)
                       for wp in wparts]
         with obs.span("profile.rows"):
-            counts = jnp.stack([jnp.asarray(r, jnp.float32) for r in rows])
+            if rows:
+                counts = jnp.stack([jnp.asarray(r, jnp.float32)
+                                    for r in rows])
+            else:           # a uniform grid alone: no split and restack
+                counts = jnp.asarray(block, jnp.float32)
         return GridProfiles(
             knobs=tuple(knobs),
             counts=counts,
@@ -1155,8 +1174,8 @@ class CostSession:
 
     # -------------------------------------------------------------- internals
     def _uniform_grid(self, cands: Sequence[GridCandidate], wl: Workload):
-        """(counts rows, totals, dacs, sorted part) for uniform-eps
-        candidates, batched.
+        """((K, P) counts, totals, dacs, sorted part, write part) for
+        uniform-eps candidates, batched.
 
         Point/range parts accumulate into the shared banded-matmul
         histograms; sorted parts accumulate into ONE merged
@@ -1168,38 +1187,38 @@ class CostSession:
             raise ValueError("Workload.n (key-file size) required for "
                              "grid estimation")
         num_pages = geom.num_pages(int(wl.n))
-        eps_arr = jnp.asarray([c.eps for c in cands], jnp.int32)
-        eps_f = np.asarray([c.eps for c in cands], np.float64)
-        dac_per_query = np.asarray(
-            dac.expected_dac(eps_f, geom.c_ipp, geom.strategy), np.float64)
+        eps_arr = np.asarray([c.eps for c in cands], np.int32)
+        dac_per_query = _uniform_dac(tuple(c.eps for c in cands),
+                                     geom.c_ipp, geom.strategy)
         sorted_parts = []
         write_parts = []
 
+        d_radius = page_ref.lut_radius(max(c.eps for c in cands),
+                                       geom.c_ipp)
+
         def grid_counts(w: Workload):
             if w.kind == POINT:
-                d_radius = page_ref.lut_radius(max(c.eps for c in cands),
-                                               geom.c_ipp)
+                (pos,), n_valid = page_ref.pad_to_bucket(w.positions)
                 counts, totals = page_ref.point_page_refs_grid(
-                    jnp.asarray(w.positions, jnp.int32), eps_arr, d_radius,
-                    geom.c_ipp, num_pages)
+                    pos, eps_arr, d_radius, geom.c_ipp, num_pages, n_valid)
                 dac_mass = dac_per_query * w.n_queries
-                return counts, np.asarray(totals, np.float64), dac_mass
+                return counts, obs.to_host(totals, np.float64), dac_mass
             if w.kind in WRITE_KINDS:
                 # locate references vary with eps (same banded kernel as
                 # point); the dirtied target window is eps-independent, so
                 # ONE shared write stream serves the whole grid (amp = 1:
                 # un-built uniform-eps candidates have no gap structure).
-                d_radius = page_ref.lut_radius(max(c.eps for c in cands),
-                                               geom.c_ipp)
-                counts, totals = page_ref.point_page_refs_grid(
-                    jnp.asarray(w.positions, jnp.int32), eps_arr, d_radius,
-                    geom.c_ipp, num_pages)
-                wcounts, wtotal = page_ref.point_page_refs(
-                    jnp.asarray(w.positions, jnp.int32), 0,
-                    geom.c_ipp, num_pages)
-                write_parts.append(WriteStreamPart(wcounts, float(wtotal)))
+                # It is the grid's extra eps-0 row: Eq. 12 at eps 0 puts
+                # each write's whole mass on its own page.
+                (pos,), n_valid = page_ref.pad_to_bucket(w.positions)
+                rows, totals = page_ref.point_page_refs_grid(
+                    pos, np.append(eps_arr, np.int32(0)), d_radius,
+                    geom.c_ipp, num_pages, n_valid)
+                totals = obs.to_host(totals, np.float64)
+                write_parts.append(WriteStreamPart(rows[-1],
+                                                   float(totals[-1])))
                 dac_mass = (dac_per_query + 1.0) * w.n_queries
-                return counts, np.asarray(totals, np.float64), dac_mass
+                return rows[:-1], totals[:-1], dac_mass
             if w.kind == RANGE:
                 counts, totals = page_ref.range_page_refs_grid(
                     jnp.asarray(w.positions, jnp.int32),
@@ -1224,11 +1243,12 @@ class CostSession:
             raise UnsupportedWorkloadError(
                 wl.kind, part=w.kind if w is not wl else None)
 
-        counts, totals, dac_mass = grid_counts(wl)
+        with obs.span("profile.uniform"):
+            counts, totals, dac_mass = grid_counts(wl)
         dacs = dac_mass / max(wl.n_queries, 1)
         spart = (_merge_sorted_parts(sorted_parts) if sorted_parts else None)
         wpart = (_merge_write_parts(write_parts) if write_parts else None)
-        return list(counts), list(totals), list(dacs), spart, wpart
+        return counts, list(totals), list(dacs), spart, wpart
 
     def _finish(self, prof: PageRefProfile, wl: Workload, cap: int,
                 t0: float) -> CamEstimate:

@@ -10,14 +10,14 @@ sorted composition, the compulsory-equivalent coverage surrogate for
 legacy coverage-less parts, exact int32 capacity clamps — so results are
 float32-equivalent to the HostExecutor (pinned by tests/test_engine.py).
 
-The histograms stay on the device, but the solve reads back to the host,
-each read a ``host_sync`` (``repro.obs``):
-
-* before the launch, each row's distinct pages ``nd_i`` and least
-  non-zero probability ``pmin``, which the kernel takes as scalars;
-* after it, the hit rates ``h2`` and the argmin ``best_id``, and, for a
-  table with sorted parts, the distinct pages ``nd_row`` of the mixed
-  histogram.
+The row statistics (the probabilities, their descending sorts, each
+row's distinct pages ``nd_i`` and least non-zero probability ``pmin``)
+are one compiled call, :func:`_row_stats`, that writes ``nd_i`` and
+``pmin`` into the kernel's scalar columns on the device, so a solve
+dispatches twice and waits on the device once.  After the launch the
+solve reads back to the host, each read a ``host_sync`` (``repro.obs``):
+the hit rates ``h2``, ``nd_i``, the argmin ``best_id``, and, for a table
+with sorted parts, the distinct pages ``nd_row`` of the mixed histogram.
 
 ``price.marshal`` spans the host work up to and including the launch,
 ``price.wait`` the first read after it.
@@ -25,8 +25,10 @@ each read a ``host_sync`` (``repro.obs``):
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -43,6 +45,66 @@ _CAP_MAX = 2**31 - 129   # matches core.session._exact_cap_array
 def _exact_i32(values) -> np.ndarray:
     arr = np.floor(np.asarray(values, np.float64))
     return np.clip(arr, -1, _CAP_MAX).astype(np.int32)
+
+
+def _stack_rows(rows, num_pages: int) -> jnp.ndarray:
+    """(K, P) float32 from per-row (P,) arrays; None rows are zero."""
+    zero = jnp.zeros((num_pages,), jnp.float32)
+    return jnp.stack([zero if r is None else jnp.asarray(r, jnp.float32)
+                      for r in rows])
+
+
+@functools.partial(jax.jit, static_argnames=("policy", "has_sorted",
+                                             "has_write"))
+def _row_stats(policy, counts_all, urows, w_rows, cov_rows, f32s, i32s,
+               caps_i, *, has_sorted, has_write):
+    """The kernel's inputs for rows ``urows`` of ``counts_all``, in one
+    compiled call.
+
+    ``f32s`` / ``i32s`` come with every column but ``nd_i`` and ``pmin``
+    filled (column 0 of ``f32s`` is each row's request mass); this fills
+    those.  Returns the kernel's arguments ``(probs, sorted_probs,
+    cov_desc, f32s, i32s, caps_f, caps_i, wprobs, wprobs_q)`` and ``(nd_i,
+    nd_row)``; ``nd_row`` is None without sorted parts.
+    """
+    num_pages = counts_all.shape[1]
+    counts = counts_all[urows]                                  # (K, P)
+    lfu = policy in ("lfu", "multi")
+    if has_write:
+        # fold the write stream into the request histogram BEFORE
+        # normalizing (hit_rate_grid order): writes fault their pages like
+        # reads, and probs/n_distinct/pmin describe the mix.
+        w_counts = _stack_rows(w_rows, num_pages)
+        counts = counts + w_counts
+    norm = jnp.maximum(f32s[:, :1], 1e-30)
+    probs = counts / norm
+    nd_i = jnp.sum(counts > 0, axis=1)
+    pmin = jnp.maximum(
+        jnp.min(jnp.where(probs > 0, probs, jnp.inf), axis=1), 1e-30)
+    f32s = f32s.at[:, 2].set(nd_i.astype(jnp.float32)).at[:, 3].set(pmin)
+    i32s = i32s.at[:, 0].set(nd_i.astype(jnp.int32))
+
+    dummy = jnp.zeros((counts.shape[0], 1), jnp.float32)
+    cov = cov_desc = dummy
+    nd_row = None
+    if has_sorted:
+        cov = _stack_rows(cov_rows, num_pages)
+        if lfu:
+            cov_desc = -jnp.sort(-cov, axis=1)
+        nd_row = jnp.sum((counts > 0) | (cov > 0), axis=1)
+    sorted_probs = -jnp.sort(-probs, axis=1) if lfu else dummy
+    wprobs = wprobs_q = None
+    if has_write:
+        wprobs = w_counts / norm
+        if lfu:
+            # the LFU resident set is the top-C of the COMBINED stream;
+            # permute write mass into that order (argsort tie-break
+            # matches cache_models._writeback_terms)
+            wprobs_q = jnp.take_along_axis(
+                wprobs, jnp.argsort(-probs, axis=1), axis=1)
+    return ((probs, sorted_probs, cov_desc, f32s, i32s,
+             caps_i.astype(jnp.float32), caps_i, wprobs, wprobs_q),
+            (nd_i, nd_row))
 
 
 class DeviceExecutor:
@@ -92,10 +154,8 @@ class DeviceExecutor:
             ids = np.full((k, c_max), _pg.PAD_ID, np.int32)
             caps_i[inv, slot] = _exact_i32(table.caps)
             ids[inv, slot] = np.arange(t, dtype=np.int32)
-            caps_f = caps_i.astype(np.float32)
 
             # ---- per-row statistics (solve_profiles preprocessing) ----------
-            counts = profiles.counts[jnp.asarray(urows)]            # (K, P)
             num_pages = int(profiles.counts.shape[1])
             sample_f = np.asarray(profiles.totals, np.float64)[urows]
             sample_f = sample_f.astype(np.float32)
@@ -103,25 +163,14 @@ class DeviceExecutor:
             wps = ([profiles.wparts[i] for i in urows]
                    if profiles.wparts else [])
             has_write = any(wp is not None for wp in wps)
+            w_rows = None
             if has_write:
-                # fold the write stream into the request histogram BEFORE
-                # normalizing (hit_rate_grid order): writes fault their pages
-                # like reads, and probs/n_distinct/pmin describe the mix.
-                zero_w = jnp.zeros((num_pages,), jnp.float32)
-                w_counts = jnp.stack(
-                    [jnp.asarray(wp.counts, jnp.float32) if wp is not None
-                     else zero_w for wp in wps])
+                w_rows = tuple(None if wp is None else wp.counts
+                               for wp in wps)
                 w_refs = np.asarray([wp.total_refs if wp is not None else 0.0
                                      for wp in wps], np.float32)
-                counts = counts + w_counts
                 sample_f = sample_f + w_refs
                 full_f = full_f + w_refs * np.float32(profiles.scale)
-            probs = counts / jnp.maximum(
-                jnp.asarray(sample_f)[:, None], 1e-30)
-            nd_i = obs.to_host(jnp.sum(counts > 0, axis=1), np.int64)
-            pmin = obs.to_host(jnp.maximum(
-                jnp.min(jnp.where(probs > 0, probs, jnp.inf), axis=1), 1e-30),
-                np.float32)
             scale = np.asarray(row_scale, np.float64)[urows].astype(np.float32)
 
             sparts = [profiles.sparts[i] for i in urows]
@@ -130,19 +179,15 @@ class DeviceExecutor:
             f32s = np.zeros((k, _pg._F32_COLS), np.float32)
             i32s = np.zeros((k, _pg._I32_COLS), np.int32)
             f32s[:, 0], f32s[:, 1] = sample_f, full_f
-            f32s[:, 2] = nd_i.astype(np.float32)
-            f32s[:, 3], f32s[:, 8] = pmin, scale
-            i32s[:, 0] = _exact_i32(nd_i)
+            f32s[:, 8] = scale                  # columns 2, 3: _row_stats
             i32s[:, 3] = upols                  # read iff policy == "multi"
 
-            dummy = jnp.zeros((k, 1), jnp.float32)
-            cov = cov_desc = dummy
+            cov_rows = None
             if has_sorted:
-                zero = SortedScanPart(
-                    0.0, 0.0, 1, jnp.zeros((num_pages,), jnp.float32), 0.0)
+                zero = SortedScanPart(0.0, 0.0, 1, None, 0.0)
                 sps = [sp if sp is not None else zero for sp in sparts]
                 for i, sp in enumerate(sps):
-                    if sp.coverage is None:
+                    if sp.coverage is None and sp is not zero:
                         surrogate[i] = sp.distinct_pages
                         sps[i] = dataclasses.replace(
                             sp, coverage=_compulsory_coverage(sp, num_pages))
@@ -152,37 +197,31 @@ class DeviceExecutor:
                 f32s[:, 6] = i32s[:, 1].astype(np.float32)
                 f32s[:, 7] = [sp.pinned_retouches for sp in sps]
                 i32s[:, 2] = _exact_i32([sp.min_capacity for sp in sps])
-                cov = jnp.stack([jnp.asarray(sp.coverage, jnp.float32)
-                                 for sp in sps])
-                if policy in ("lfu", "multi"):
-                    cov_desc = -jnp.sort(-cov, axis=1)
-            sorted_probs = (-jnp.sort(-probs, axis=1)
-                            if policy in ("lfu", "multi") else dummy)
-            wprobs = wprobs_q = None
-            if has_write:
-                wprobs = w_counts / jnp.maximum(
-                    jnp.asarray(sample_f)[:, None], 1e-30)
-                if policy in ("lfu", "multi"):
-                    # the LFU resident set is the top-C of the COMBINED stream;
-                    # permute write mass into that order (argsort tie-break
-                    # matches cache_models._writeback_terms)
-                    wprobs_q = jnp.take_along_axis(
-                        wprobs, jnp.argsort(-probs, axis=1), axis=1)
+                cov_rows = tuple(sp.coverage for sp in sps)
+
+            kernel_args, (nd_dev, nd_row_dev) = _row_stats(
+                policy, profiles.counts, urows.astype(np.int32), w_rows,
+                cov_rows, f32s, i32s, caps_i, has_sorted=has_sorted,
+                has_write=has_write)
+            (probs, sorted_probs, cov_desc, f32s, i32s, caps_f, caps_i,
+             wprobs, wprobs_q) = kernel_args
 
             # ---- one fused launch -------------------------------------------
             h2, _, best_id = _pg.price_grid(
-                policy, probs, sorted_probs, cov_desc,
-                jnp.asarray(f32s), jnp.asarray(i32s), jnp.asarray(caps_f),
-                jnp.asarray(caps_i), jnp.asarray(ids), wprobs, wprobs_q,
-                has_sorted=has_sorted, has_write=has_write,
+                policy, probs, sorted_probs, cov_desc, f32s, i32s, caps_f,
+                caps_i, ids, wprobs, wprobs_q, has_sorted=has_sorted,
+                has_write=has_write,
                 interpret=kernel_ops._auto_interpret(self.interpret))
+            for out in (h2, nd_dev, best_id, nd_row_dev):
+                if out is not None:     # every read below rides one wait
+                    out.copy_to_host_async()
         with obs.span("price.wait"):
             h = obs.to_host(h2, np.float64)[inv, slot]
+        nd_i = obs.to_host(nd_dev, np.int64)
 
         # ---- distinct pages (host-side closed forms, as solve_profiles) -
         if has_sorted:
-            nd_row = obs.to_host(
-                jnp.sum((counts > 0) | (cov > 0), axis=1), np.float64)
+            nd_row = obs.to_host(nd_row_dev, np.float64)
             for i, true_n in surrogate.items():
                 nd_row[i] = float(nd_i[i]) + true_n
         else:

@@ -165,6 +165,7 @@ class WriteSession:
         stolen = self.delta.stolen_pages(self.system.geom.page_bytes)
         return max(self.cap_empty - stolen, 0)
 
+    @obs.span("write.burst")
     def _burst_profiles(self) -> Tuple[GridProfiles, int]:
         burst = merge_burst_workload(self.delta.positions(), self.n,
                                      self.system.geom.c_ipp)
@@ -204,7 +205,8 @@ class WriteSession:
         if reads is not None:
             self.sketch.update(reads)
         if writes is not None:
-            self.delta.stage(writes)
+            with obs.span("write.stage"):
+                self.delta.stage(writes)
         if len(self.sketch) == 0:
             # nothing priceable yet (pure-write prefix): stage and wait
             return BatchRecord(i, n_reads, n_writes, self.delta.entries,

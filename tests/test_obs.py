@@ -131,9 +131,9 @@ def test_host_reads_are_counted_under_their_spans(traced):
     assert syncs.count("profile.wait") == len(reports)
     assert syncs.count("sketch.chunk") == len(reports)
     assert syncs.count("price.wait") == n_retunes
-    # nd_i and pmin are read before the launch, best_id after the wait
-    assert syncs.count("price.marshal") == 2 * n_retunes
-    assert syncs.count("engine.price") == n_retunes
+    # nothing is read before the launch; nd_i and best_id after the wait
+    assert syncs.count("price.marshal") == 0
+    assert syncs.count("engine.price") == 2 * n_retunes
     sync_bytes = [c for c in reg["counts"] if c[0] == "host_sync_bytes"]
     assert len(sync_bytes) == len(syncs)
     assert all(c[2] > 0 for c in sync_bytes)

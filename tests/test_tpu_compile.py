@@ -6,7 +6,8 @@ VMEM), so every other kernel test can pass while the device executor
 cannot run at all.  These tests hand both kernels to the TPU compiler for
 a described, unattached ``v5e:2x2`` topology at ``chip_smoke.py``'s shapes
 (9 profile rows, 7,813 pages of 256 items from 2M keys, 12 cells per row,
-200k point queries).  Nothing runs; a refusal raises.
+200k point queries), and ``price_grid`` also at the write cell's launch.
+Nothing runs; a refusal raises.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
@@ -48,27 +49,39 @@ def _shape(one_chip, shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
 
-@pytest.mark.parametrize("policy,has_sorted,has_write", [
-    ("lru", False, False), ("lru", True, False),
-    ("fifo", False, False), ("fifo", True, False),
-    ("lfu", False, False), ("lfu", True, False),
-    ("multi", False, False), ("multi", True, False),
-    ("lru", False, True),
-])
+#: The write cell's launch (``bench/configs/osm_pgm.json``): the read row
+#: and the merge burst's row over 31,250 pages, two cells on the first.
+#: The cell launches ``("lfu", True, False)``; ``("lfu", True, True)`` is
+#: the same launch with a write stream.
+WRITE_CELL = (2, 31_250, 2)
+
+
+@pytest.mark.parametrize("policy,has_sorted,has_write,shape", [
+    pytest.param(p, s, w, (K, PAGES, CELLS), id=f"{p}-{s}-{w}")
+    for p, s, w in [
+        ("lru", False, False), ("lru", True, False),
+        ("fifo", False, False), ("fifo", True, False),
+        ("lfu", False, False), ("lfu", True, False),
+        ("multi", False, False), ("multi", True, False),
+        ("lru", False, True)]
+] + [pytest.param("lfu", True, True, WRITE_CELL, id="lfu-True-True"),
+      pytest.param("lfu", True, False, WRITE_CELL,
+                    id="lfu-True-False-write-cell")])
 def test_price_grid_compiles_for_v5e(one_chip, policy, has_sorted,
-                                     has_write):
+                                     has_write, shape):
     lfu = policy in ("lfu", "multi")
-    rows = _shape(one_chip, (K, PAGES))
-    unused = _shape(one_chip, (K, 1))
+    k, pages, cells = shape
+    rows = _shape(one_chip, (k, pages))
+    unused = _shape(one_chip, (k, 1))
     args = [
         rows,
         rows if lfu else unused,                          # sorted_probs
         rows if lfu and has_sorted else unused,           # cov_desc
-        _shape(one_chip, (K, pg._F32_COLS)),
-        _shape(one_chip, (K, pg._I32_COLS), jnp.int32),
-        _shape(one_chip, (K, CELLS)),
-        _shape(one_chip, (K, CELLS), jnp.int32),
-        _shape(one_chip, (K, CELLS), jnp.int32),
+        _shape(one_chip, (k, pg._F32_COLS)),
+        _shape(one_chip, (k, pg._I32_COLS), jnp.int32),
+        _shape(one_chip, (k, cells)),
+        _shape(one_chip, (k, cells), jnp.int32),
+        _shape(one_chip, (k, cells), jnp.int32),
         rows if has_write else None,                      # wprobs
         rows if has_write and lfu else None,              # wprobs_q
     ]
