@@ -19,15 +19,26 @@ class's Eq. 12 LUT, centered on the grid-wide max radius D, into one
     lutstack[d, c * C_ipp + s] = LUT_c[s, d - (D - D_c)]      (W, n_c*C_ipp)
 
 and encode each query as the combined key ``code * C_ipp + slot``.  Then
-for one candidate row and one query tile:
+for one candidate row, one page tile (columns ``[j0, j0 + PT)``) and one
+query tile:
 
     SEL[cs, q] = [key_q == cs]              one-hot     (n_c*C_ipp, QT)
     T1         = lutstack @ SEL             banded mass (W, QT)
-    counts[page_q + d] += T1[d, q]          for d in [0, W)
+    OH[q, c]   = [page_q == j0 - L + c]     one-hot     (QT, PT + L)
+    M          = T1 @ OH                                (W, PT + L)
+    counts[j0 + j] += sum_d M[d, j + L - d]             j in [0, PT)
 
-and the scatter in the last line is itself W one-hot matmuls
-``T1[d] @ [page_q + d == j]`` — no gathers, no scatters, pure iota
-compares and MXU work.  Padded queries carry key -1 and never match.
+since ``counts[page_q + d] += T1[d, q]`` for d in [0, W) is
+``sum_d M[d, j - d]`` with M's columns offset by the margin L.  L is
+``W - 1`` rounded up to the 128-lane width, which keeps each band row's
+turn within one vreg for Mosaic's strided ``pltpu.roll``: one roll per
+128 band rows lines the diagonals up, and a sublane sum adds them.  One
+one-hot and three MXU passes per program, whatever W; no gathers, no
+scatters, only iota compares, MXU work and lane rotations.  Both
+contractions split their f32 operand into three bf16 parts against a
+0/1 one-hot (:func:`_dot_onehot`), so every product is exact and only the
+f32 summation order differs from the host.  Padded queries carry key -1
+and never match.
 
 The output is the SAME padded ``(K, P + 2D)`` layout the host kernel
 accumulates into (out-of-range window mass lands in the pad and is
@@ -35,7 +46,8 @@ sliced off); :func:`point_page_refs_mixed_eps_grid` mirrors the host
 function's signature and slicing exactly.  Equivalence: exact for
 integer-mass inputs (every LUT entry 0 or 1 — f32 sums of integers), and
 float32-tolerance otherwise; pinned host-vs-device by
-tests/test_kernels.py across families x policies x workloads.
+tests/test_kernels.py across families x policies x workloads and band
+widths of 3 to 65 pages over several page tiles.
 
 Grid = (K rows, page tiles, query tiles); each program owns one
 candidate row x one page-tile block of the padded histogram and
@@ -43,13 +55,11 @@ accumulates its query tiles into the revisited block (zero-initialized on
 the first visit), so VMEM stays bounded whatever the workload size.
 Interpret mode off-TPU via the shared ``kernels.ops._auto_interpret``
 rule.  On a TPU v5e it compiles (tests/test_tpu_compile.py) at every P
-tried, up to 4M pages: a program's VMEM depends on the tiles, the band
-width and the stacked LUT width, not on P.  The band loop is unrolled and
-no ``vmem_limit_bytes`` is set, so wide bands do overflow v5e's 16 MiB of
-scoped VMEM: RMI branches 64..512 over 8M books keys quantize their leaf
-errors to 4,096, a 65-page band, and at 10-13 eps classes need
-16.7-17.0 MB (refused on the chip and by the v5e compiler); 33-page bands
-compile.
+tried, up to 4M pages: a program's VMEM depends on the tiles and the
+stacked LUT width (the SEL one-hot), and on W only through the
+(W, PT + L) block M, so the 65-page bands of RMI branches 64..512 over
+8M books keys (13 eps classes) compile within v5e's 16 MiB of scoped
+VMEM with no ``vmem_limit_bytes`` set.
 
 Host work per call, each step a program span (``repro.obs``):
 ``profile.prep`` (class codes, dense rank, the LUT stack, the transfers
@@ -74,8 +84,12 @@ __all__ = ["profile_grid", "point_page_refs_mixed_eps_grid"]
 
 _LANES = 128
 _SUBLANES = 8
-_Q_TILE = 512        # queries resident per program
-_P_TILE = 2048       # padded-histogram columns per program
+# A wider page tile recomputes T1 for fewer tiles; a narrower query tile
+# keeps the wider one-hot within scoped VMEM.  Against 512 x 2048 on a
+# v5e, 256 x 8192 halves the kernel's time and compiles for every band
+# width and class count the narrower tile did.
+_Q_TILE = 256        # queries resident per program
+_P_TILE = 8192       # padded-histogram columns per program
 
 
 def _ceil_to(n: int, m: int) -> int:
@@ -101,7 +115,7 @@ def _dot_onehot(x, onehot):
 
 
 def _occupancy_kernel(keys_ref, pages_ref, lut_ref, out_ref, *,
-                      width: int, n_cc: int, q_tile: int, p_tile: int):
+                      n_cc: int, q_tile: int, p_tile: int, margin: int):
     """One program = one candidate row x one page tile x one query tile."""
     pt_i = pl.program_id(1)
     qt_i = pl.program_id(2)
@@ -120,13 +134,22 @@ def _occupancy_kernel(keys_ref, pages_ref, lut_ref, out_ref, *,
            == keys).astype(jnp.bfloat16)
     t1 = _dot_onehot(lut, sel)                              # (Wp, QT)
 
-    page_col = pages.T                                      # (QT, 1)
-    base = (jax.lax.broadcasted_iota(jnp.int32, (q_tile, p_tile), 1)
-            + pt_i * p_tile)                                # global column
+    # one one-hot over the page tile and the lane-aligned left margin:
+    # column c is global column pt_i*PT - margin + c
+    oh = (jax.lax.broadcasted_iota(jnp.int32, (q_tile, p_tile + margin), 1)
+          + (pt_i * p_tile - margin) == pages.T).astype(jnp.bfloat16)
+    m = _dot_onehot(t1, oh)                                 # (Wp, PT+margin)
+    # band row d sits margin-d columns right of its output column: a
+    # strided roll turns row d left by that much (right by ext-margin+d).
+    # Mosaic keeps a strided roll's spread of turns within one vreg, hence
+    # one roll per 128 band rows, each based on a whole number of vregs.
+    # The first PT columns never wrap; rows past the band are zero.
+    ext = p_tile + margin
     acc = out_ref[...]
-    for d in range(width):
-        oh = (base == page_col + d).astype(jnp.bfloat16)    # (QT, PT)
-        acc = acc + _dot_onehot(t1[d:d + 1, :], oh)
+    for r0 in range(0, m.shape[0], _LANES):
+        band = pltpu.roll(m[r0:r0 + _LANES], (ext - margin + r0) % ext, 1,
+                          stride=1, stride_axis=0)
+        acc = acc + jnp.sum(band, axis=0, keepdims=True)[:, :p_tile]
     out_ref[...] = acc
 
 
@@ -159,12 +182,13 @@ def profile_grid(keys, pages, lutstack, *, width: int, pad: int,
         keys = jnp.pad(keys, ((0, 0), (0, qp - q)), constant_values=-1)
         pages = jnp.pad(pages, ((0, 0), (0, qp - q)), constant_values=-1)
     n_cc = int(lutstack.shape[1])
+    margin = _ceil_to(width - 1, _LANES)
 
     # rows ride a squeezed leading axis: each program sees (1, tile)
     # blocks, which Mosaic accepts for any K
     out = pl.pallas_call(
-        functools.partial(_occupancy_kernel, width=width, n_cc=n_cc,
-                          q_tile=q_tile, p_tile=p_tile),
+        functools.partial(_occupancy_kernel, n_cc=n_cc, q_tile=q_tile,
+                          p_tile=p_tile, margin=margin),
         grid=(k, pp // p_tile, qp // q_tile),
         in_specs=[
             pl.BlockSpec((None, 1, q_tile), lambda i, p, t: (i, 0, t)),

@@ -184,3 +184,63 @@ def test_occupancy_ragged_shapes(q, num_pages):
     scale = max(1.0, float(ch.max()))
     assert np.max(np.abs(ch - cd)) / scale < 2e-6
     assert np.max(np.abs(th - td) / np.maximum(th, 1.0)) < 2e-6
+
+
+#: Pages past two page tiles of the padded histogram, so every band
+#: crosses a tile edge somewhere and the last tile is ragged.
+TILE = profile_grid._P_TILE
+WIDE_PAGES = 2 * TILE + 104
+
+
+def _edge_positions(rng, radius, q, slots):
+    """Queries on page 0, on the last page and on both sides of each page
+    tile edge (padded column = page + radius), the rest spread over the
+    key file; ``slots(n)`` draws the in-page offsets."""
+    edges = [e - radius + o for e in (TILE, 2 * TILE) for o in (-2, -1, 0, 1)]
+    pages = np.concatenate([
+        [0, 0, 1, WIDE_PAGES - 1, WIDE_PAGES - 1, WIDE_PAGES - 2],
+        np.clip(edges, 0, WIDE_PAGES - 1),
+        rng.integers(0, WIDE_PAGES, q)])
+    return pages * C_IPP + slots(len(pages))
+
+
+@pytest.mark.parametrize("radius", [1, 16, 32])
+def test_occupancy_wide_band_integer_mass_exact(radius):
+    """Bands of 3, 33 and 65 pages over several page tiles.  The first two
+    rows keep eps <= 4 and slots >= 2*eps from both page edges, so their
+    mass is integer and must match EXACTLY whatever the band's width; the
+    last row alone carries the eps that sets the band (fractional mass,
+    float32 tolerance)."""
+    rng = np.random.default_rng(100 + radius)
+    positions = _edge_positions(rng, radius, 1200,
+                                lambda n: rng.integers(16, 112, n))
+    q = len(positions)
+    eps_rows = np.concatenate([
+        rng.choice([1, 2, 4], size=(2, q)),
+        np.full((1, q), radius * C_IPP // 2)]).astype(np.int64)
+    assert page_ref.lut_radius(int(eps_rows.max()), C_IPP) == radius
+    ch, th, cd, td = _occupancy_pair(positions, eps_rows, WIDE_PAGES)
+    assert np.all(ch[:2] == np.round(ch[:2]))
+    assert np.array_equal(ch[:2], cd[:2])
+    assert np.array_equal(th[:2], td[:2])
+    scale = max(1.0, float(ch[2].max()))
+    assert np.max(np.abs(ch[2] - cd[2])) / scale < 2e-6
+
+
+@pytest.mark.parametrize("radius", [1, 16, 32])
+def test_occupancy_wide_band_general_tolerance(radius):
+    """The same bands and tile edges with arbitrary slots and every pow2
+    eps class up to the band's own: fractional mass in every band row,
+    within the float32 tolerance of the host oracle."""
+    rng = np.random.default_rng(200 + radius)
+    positions = _edge_positions(rng, radius, 2500,
+                                lambda n: rng.integers(0, C_IPP, n))
+    q = len(positions)
+    top = radius * C_IPP // 2
+    classes = [1 << b for b in range(top.bit_length())]
+    eps_rows = rng.choice(classes, size=(3, q)).astype(np.int64)
+    eps_rows[:, 0] = top
+    ch, th, cd, td = _occupancy_pair(positions, eps_rows, WIDE_PAGES)
+    scale = max(1.0, float(ch.max()))
+    assert np.max(np.abs(ch - cd)) / scale < 2e-6
+    assert np.max(np.abs(th - td) / np.maximum(th, 1.0)) < 2e-6

@@ -94,10 +94,16 @@ def test_price_grid_compiles_for_v5e(one_chip, policy, has_sorted,
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_profile_grid_compiles_for_v5e(one_chip):
+@pytest.mark.parametrize("rows,radius,classes", [
     # the smoke's RMI branch grid: 11 candidates whose leaves mix 12 pow2
     # eps classes, the widest a 39-page band
-    rows, radius, classes, c_ipp = 11, 19, 12, 256
+    pytest.param(11, 19, 12, id="39-page-band"),
+    # the published books grid's branches 64..512: leaf errors quantized
+    # to 4,096, a 65-page band, over 13 eps classes
+    pytest.param(11, 32, 13, id="65-page-band"),
+])
+def test_profile_grid_compiles_for_v5e(one_chip, rows, radius, classes):
+    c_ipp = 256
     width = 2 * radius + 1
 
     def occupancy(keys, pages, lut):
@@ -107,5 +113,5 @@ def test_profile_grid_compiles_for_v5e(one_chip):
     compiled = jax.jit(occupancy).lower(
         _shape(one_chip, (rows, QUERIES), jnp.int32),
         _shape(one_chip, (1, QUERIES), jnp.int32),
-        _shape(one_chip, (40, classes * c_ipp))).compile()
+        _shape(one_chip, (-(-width // 8) * 8, classes * c_ipp))).compile()
     assert "tpu_custom_call" in compiled.as_text()
