@@ -31,8 +31,8 @@ def main() -> None:
 
     from benchmarks import (bench_covariance, bench_engine,
                             bench_estimate_grid, bench_fetch_strategy,
-                            bench_io_size, bench_join, bench_kernels,
-                            bench_kv_planner, bench_pgm_tuning_curve,
+                            bench_io_size, bench_join, bench_kv_planner,
+                            bench_pgm_tuning_curve,
                             bench_point_accuracy, bench_profile_grid,
                             bench_range_accuracy, bench_rmi_tuning_curve,
                             bench_serving_drift, bench_sharding,
@@ -48,7 +48,6 @@ def main() -> None:
         "rmi_tuning_curve": bench_rmi_tuning_curve.run,  # Fig 8
         "tuning_e2e": bench_tuning_e2e.run,             # Figs 9/10
         "join": bench_join.run,                         # Fig 11
-        "kernels": bench_kernels.run,                   # che_solver kernel
         "kv_planner": bench_kv_planner.run,             # beyond-paper (Eq.15 serving)
         "estimate_grid": bench_estimate_grid.run,       # CostSession grid vs loop
         "serving_drift": bench_serving_drift.run,       # adaptive vs static

@@ -43,6 +43,8 @@ sys.path.insert(0, os.path.join(ROOT, "src"))
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
+# the forcing switch would override the executors each phase passes
+from repro.backend import ENV_EXECUTOR  # noqa: E402
 from repro.core.cam import CamGeometry  # noqa: E402
 from repro.core.cache_models import POLICIES  # noqa: E402
 from repro.core.qerror import q_error  # noqa: E402
@@ -61,8 +63,6 @@ from repro.write import CamMergeScheduler, WriteConfig, WriteSession  # noqa: E4
 
 HIT_TOL = 2e-6        # tests/test_engine.py: host vs device solved hit rates
 QERR_BOUND = 1.4      # tests/test_session.py: estimator vs replay, point
-# the forcing switch would override the executors each phase passes
-ENV_EXECUTOR = "REPRO_ENGINE_EXECUTOR"
 
 
 def log(**fields):

@@ -2,10 +2,7 @@
 # Tier-1 CI step — the single source of truth; .github/workflows/ci.yml
 # invokes this script.
 #
-# Deselects the genuinely environment-limited tests (marked env_limited in
-# tests/, registered in pyproject.toml: XLA cost-model tolerances and the
-# >1-device production-mesh dry-run) so the suite is green-on-regression on a
-# single-device CPU runner, then smokes the benchmarks covering the batched
+# Runs the whole test suite, then smokes the benchmarks covering the batched
 # estimation paths (point/range grid kernels AND the policy-aware sorted
 # grid), the tuning curve, the end-to-end tuner comparison (which records
 # the mixed-eps-kernel speedup to benchmarks/results/tuning_e2e.json),
@@ -34,7 +31,7 @@ set -e
 cd "$(dirname "$0")/.."
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
 
-python -m pytest -x -q -m "not env_limited"
+python -m pytest -x -q
 python -m benchmarks.run --smoke --only estimate_grid pgm_tuning_curve
 python -m benchmarks.bench_tuning_e2e --smoke
 python -m benchmarks.bench_join --smoke

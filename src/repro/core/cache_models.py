@@ -127,21 +127,9 @@ def solve_che_time(probs: jnp.ndarray, capacity) -> jnp.ndarray:
     return _bisect(objective, lo, hi)
 
 
-def hit_rate_lru(probs: jnp.ndarray, capacity, use_kernel: bool = False
-                 ) -> jnp.ndarray:
-    """Che's approximation for LRU (Eq. 7).
-
-    ``use_kernel=True`` solves the characteristic time with the Pallas
-    multi-candidate evaluator (kernels/che_solver.py): K=8 candidates per
-    HBM pass, 4x less popularity-array traffic on TPU (interpret-mode on
-    CPU, so opt-in here; validated equivalent in tests/test_kernels.py).
-    """
-    if use_kernel:
-        from repro.kernels import ops as kernel_ops
-
-        t_c = kernel_ops.che_solve(probs, capacity, k=8, iters=16)
-    else:
-        t_c = solve_che_time(probs, capacity)
+def hit_rate_lru(probs: jnp.ndarray, capacity) -> jnp.ndarray:
+    """Che's approximation for LRU (Eq. 7)."""
+    t_c = solve_che_time(probs, capacity)
     return jnp.sum(probs * -jnp.expm1(-probs * t_c))
 
 

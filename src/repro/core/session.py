@@ -22,7 +22,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import os
 import time
 from typing import (Dict, NamedTuple, Optional, Protocol, Sequence, Tuple,
                     runtime_checkable)
@@ -31,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
+from repro.backend import resolve_executor
 from repro.core import cache_models, dac, page_ref
 from repro.core.cam import CamEstimate, CamGeometry, capacity_pages
 from repro.core.workload import (INSERT, MIXED, POINT, RANGE, SORTED,
@@ -312,25 +312,6 @@ def _compulsory_coverage(sp: SortedScanPart, num_pages: int) -> jnp.ndarray:
     """
     return jnp.zeros((num_pages,), jnp.float32).at[0].set(
         jnp.float32(sp.total_refs))
-
-
-def _resolve_profile_executor(executor: Optional[str]) -> str:
-    """Profiling-side executor dispatch, mirroring ``PricingEngine._resolve``:
-    an explicit argument wins, then the ``REPRO_ENGINE_EXECUTOR`` environment
-    variable, then auto — ``device`` on a TPU backend, ``host`` elsewhere.
-    ``host`` is the golden ``np.bincount`` mixed-eps kernel; ``device`` the
-    banded one-hot matmul kernel (``kernels/profile_grid.py``), whose
-    histograms are born in HBM and chain into the fused pricing launch.
-    """
-    if executor is None:
-        executor = os.environ.get("REPRO_ENGINE_EXECUTOR") or None
-    if executor is None:
-        import jax
-        executor = "device" if jax.default_backend() == "tpu" else "host"
-    if executor not in ("host", "device"):
-        raise ValueError(f"unknown profile executor {executor!r}; expected "
-                         "'host' or 'device'")
-    return executor
 
 
 def _exact_cap_array(values) -> jnp.ndarray:
@@ -702,8 +683,8 @@ class CostSession:
         ``executor`` picks the mixed-eps kernel: ``"host"`` (the golden
         ``np.bincount`` path), ``"device"`` (the banded one-hot matmul
         kernel of ``kernels/profile_grid.py`` — histograms stay in HBM and
-        chain into the fused pricing launch), or ``None`` for the engine's
-        dispatch rule (``REPRO_ENGINE_EXECUTOR``, then auto-TPU).
+        chain into the fused pricing launch), or ``None`` for
+        :func:`repro.backend.resolve_executor`'s rule.
         """
         wl = self._sampled(workload, sample_rate, seed)
         feasible, skipped = self._feasible(candidates)
@@ -1063,7 +1044,7 @@ class CostSession:
                 ok_dacs.append(float(e_dac))
         if ok:
             num_pages = geom.num_pages(int(ok[0].index.n))
-            if _resolve_profile_executor(executor) == "device":
+            if resolve_executor(executor) == "device":
                 from repro.kernels import profile_grid as _device_profile
                 counts_b, totals_b = \
                     _device_profile.point_page_refs_mixed_eps_grid(

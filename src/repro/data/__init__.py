@@ -1,5 +1,4 @@
-"""Data substrate: synthetic SOSD-style datasets, workload mixtures, and the
-LM token pipeline."""
+"""Data substrate: synthetic SOSD-style datasets and workload mixtures."""
 from repro.data import datasets, workloads
 
 __all__ = ["datasets", "workloads"]

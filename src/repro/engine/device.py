@@ -33,8 +33,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
+from repro.backend import default_interpret
 from repro.core.session import SortedScanPart, _compulsory_coverage
-from repro.kernels import ops as kernel_ops
 from repro.kernels import price_grid as _pg
 
 __all__ = ["DeviceExecutor"]
@@ -211,7 +211,7 @@ class DeviceExecutor:
                 policy, probs, sorted_probs, cov_desc, f32s, i32s, caps_f,
                 caps_i, ids, wprobs, wprobs_q, has_sorted=has_sorted,
                 has_write=has_write,
-                interpret=kernel_ops._auto_interpret(self.interpret))
+                interpret=default_interpret(self.interpret))
             for out in (h2, nd_dev, best_id, nd_row_dev):
                 if out is not None:     # every read below rides one wait
                     out.copy_to_host_async()

@@ -33,20 +33,20 @@ Two interchangeable executors do the solving:
   the sorted/mixed composition and the objective argmin run in one kernel
   launch (float32-equivalent; interpret mode off-TPU).
 
-Dispatch rule: an explicit ``executor=`` argument wins, then the
-``REPRO_ENGINE_EXECUTOR`` environment variable (``host`` / ``device``), then
-the engine's constructor default, then auto — ``device`` on a TPU backend,
-``host`` everywhere else (mirroring ``kernels.ops._auto_interpret``).
+Dispatch rule (:func:`repro.backend.resolve_executor`): an explicit
+``executor=`` argument wins, then the ``REPRO_ENGINE_EXECUTOR`` environment
+variable (``host`` / ``device``), then the engine's constructor default,
+then auto — ``device`` on a TPU backend, ``host`` everywhere else.
 """
 from __future__ import annotations
 
 import dataclasses
-import os
 from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
 from repro import obs
+from repro.backend import resolve_executor
 
 __all__ = ["PriceTable", "PriceSolution", "PricingEngine"]
 
@@ -292,10 +292,11 @@ class PriceSolution:
 class PricingEngine:
     """profile -> solve -> argmin behind ONE call, bound to a CostSession.
 
-    ``executor`` pins an executor for every ``price`` call (``"host"`` /
-    ``"device"`` / an executor instance); ``None`` resolves per call — the
-    ``REPRO_ENGINE_EXECUTOR`` env var if set, else ``device`` on a TPU
-    backend and ``host`` everywhere else.  ``calls`` counts ``price``
+    ``executor`` is the engine's default executor (``"host"`` /
+    ``"device"`` / an executor instance).  A call's own ``executor=`` and
+    the ``REPRO_ENGINE_EXECUTOR`` env var both override it; with neither
+    and ``None`` here, ``device`` on a TPU backend and ``host`` everywhere
+    else (:func:`repro.backend.resolve_executor`).  ``calls`` counts ``price``
     invocations: every session runs exactly one per search, structurally
     asserted in the test suite.
     """
@@ -308,25 +309,16 @@ class PricingEngine:
 
     # ------------------------------------------------------------- dispatch
     def _resolve(self, executor):
-        if executor is None:
-            executor = os.environ.get("REPRO_ENGINE_EXECUTOR") or None
-        if executor is None:
-            executor = self.executor
-        if executor is None:
-            import jax
-            executor = "device" if jax.default_backend() == "tpu" else "host"
+        executor = resolve_executor(executor, self.executor)
         if not isinstance(executor, str):
             return executor
         if executor not in self._instances:
             if executor == "host":
                 from repro.engine.host import HostExecutor
                 self._instances[executor] = HostExecutor()
-            elif executor == "device":
+            else:
                 from repro.engine.device import DeviceExecutor
                 self._instances[executor] = DeviceExecutor()
-            else:
-                raise ValueError(f"unknown executor {executor!r}; expected "
-                                 "'host' or 'device'")
         return self._instances[executor]
 
     # ---------------------------------------------------------------- price

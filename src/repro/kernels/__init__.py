@@ -1,13 +1,10 @@
-"""Pallas TPU kernels for the perf-critical hot spots.
+"""Pallas TPU kernels for the two device steps of profile -> price.
 
-* flash_attention  — prefill/train blockwise attention (MXU-tiled).
-* decode_attention — split-K single-token GQA decode.
-* che_solver       — multi-candidate Che fixed-point evaluation (the CAM
-                     tuning hot loop; K candidates per HBM pass).
+* profile_grid — mixed-eps occupancy histograms (banded one-hot matmul).
+* price_grid   — the fused policy fixed point, composition and argmin.
 
-Each kernel ships with ops.py (jit'd wrapper, auto interpret off-TPU) and
-ref.py (pure-jnp oracle used by the allclose test sweeps).
+Off a TPU both run in interpret mode (``repro.backend.default_interpret``).
 """
-from repro.kernels import ops, ref
+from repro.kernels import price_grid, profile_grid
 
-__all__ = ["ops", "ref"]
+__all__ = ["profile_grid", "price_grid"]

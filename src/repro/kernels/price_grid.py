@@ -1,8 +1,7 @@
 """Fused PriceTable solve: policy fixed point + sorted/mixed composition +
 objective argmin in ONE pallas launch (the DeviceExecutor hot path).
 
-Generalizes ``che_solver.py``'s K-candidates-per-HBM-pass idiom from one
-histogram x K characteristic times to K histograms x C capacities: each
+Prices K histograms x C capacities with one HBM pass per histogram: each
 grid program loads ONE profile row's popularity histogram into VMEM and
 prices ALL of that row's table cells against it — the Che/Fricker
 bisection (or the LFU top-C mass) runs lockstep over the row's C

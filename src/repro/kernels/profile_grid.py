@@ -53,7 +53,7 @@ Grid = (K rows, page tiles, query tiles); each program owns one
 candidate row x one page-tile block of the padded histogram and
 accumulates its query tiles into the revisited block (zero-initialized on
 the first visit), so VMEM stays bounded whatever the workload size.
-Interpret mode off-TPU via the shared ``kernels.ops._auto_interpret``
+Interpret mode off-TPU via the shared ``repro.backend.default_interpret``
 rule.  On a TPU v5e it compiles (tests/test_tpu_compile.py) at every P
 tried, up to 4M pages: a program's VMEM depends on the tiles and the
 stacked LUT width (the SEL one-hot), and on W only through the
@@ -77,8 +77,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro import obs
+from repro.backend import default_interpret
 from repro.core import page_ref
-from repro.kernels import ops as kernel_ops
 
 __all__ = ["profile_grid", "point_page_refs_mixed_eps_grid"]
 
@@ -272,7 +272,7 @@ def point_page_refs_mixed_eps_grid(
             jnp.asarray(keys), jnp.asarray(page[None, :]),
             jnp.asarray(_lut_stack(class_eps, c_ipp, max_radius)),
             width=2 * max_radius + 1, pad=pad,
-            interpret=kernel_ops._auto_interpret(interpret))
+            interpret=default_interpret(interpret))
         counts = padded[:, max_radius:max_radius + num_pages]
         totals = jnp.sum(counts, axis=1)
     with obs.span("profile.wait"):

@@ -1,4 +1,4 @@
-"""Serving plane: batched engine, paged KV pool, CAM-guided pool planner."""
-from repro.serve import engine, kv_cache, planner
+"""KV-pool planner: CAM's cost model sizing a paged KV block pool."""
+from repro.serve import kv_cache, planner
 
-__all__ = ["engine", "kv_cache", "planner"]
+__all__ = ["kv_cache", "planner"]

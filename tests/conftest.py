@@ -3,10 +3,10 @@
 ``engine_executor`` is THE way a test forces the engine/profiling
 dispatch: it scopes ``REPRO_ENGINE_EXECUTOR`` through monkeypatch so the
 forcing can never leak into another test (a bare ``os.environ`` write
-would).  The env var is read per call by both dispatch sites —
-``engine.table.PricingEngine._resolve`` (price side) and
-``core.session._resolve_profile_executor`` (profile side) — so one
-fixture steers both halves of the chained profile→price path.
+would).  The env var is read per call by ``repro.backend.resolve_executor``,
+which both the price side (``PricingEngine._resolve``) and the profile side
+(``CostSession``'s mixed-eps pass) call, so one fixture steers both halves
+of the chained profile→price path.
 """
 import pytest
 

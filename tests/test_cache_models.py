@@ -21,22 +21,43 @@ def zipf_probs(n, a=1.2, seed=0):
 # Fixed-point consistency
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("cap_frac", [0.05, 0.3, 0.7])
-def test_che_consistency(cap_frac):
-    probs = jnp.asarray(zipf_probs(5000), jnp.float32)
-    cap = cap_frac * 5000
-    t = cm.solve_che_time(probs, cap)
-    lhs = float(jnp.sum(-jnp.expm1(-probs * t)))
-    assert abs(lhs - cap) / cap < 1e-3
+def _consistency_ref(policy, probs, cap):
+    """Characteristic time by float64 bisection on the host: Che's Eq. 8
+    for LRU, Fricker's Eq. 5 for FIFO."""
+    p = np.asarray(probs, np.float64)
+
+    def occupancy(t):
+        if policy == "lru":
+            return np.sum(-np.expm1(-p * t))
+        return np.sum(p * t / (1.0 - p + p * t))
+
+    lo, hi = 0.0, 1.0
+    while occupancy(hi) < cap:
+        hi *= 2.0
+    for _ in range(200):
+        mid = 0.5 * (lo + hi)
+        lo, hi = (mid, hi) if occupancy(mid) < cap else (lo, mid)
+    return 0.5 * (lo + hi)
 
 
 @pytest.mark.parametrize("cap_frac", [0.05, 0.3, 0.7])
-def test_fifo_consistency(cap_frac):
-    probs = jnp.asarray(zipf_probs(5000), jnp.float32)
+@pytest.mark.parametrize("a", [0.9, 1.1, 1.3])
+@pytest.mark.parametrize("policy", ["lru", "fifo"])
+def test_fixed_point_consistency(policy, a, cap_frac):
+    """The characteristic time solves its consistency condition (LRU: Che's
+    sum of 1 - exp(-p T) = C; FIFO: Fricker's occupancy sum = C) to 1e-3,
+    and agrees with a float64 host bisection to 1e-3 relative."""
+    probs = jnp.asarray(zipf_probs(5000, a=a), jnp.float32)
     cap = cap_frac * 5000
-    tau = cm.solve_fifo_tau(probs, cap)
-    occ = probs * tau / (1.0 - probs + probs * tau)
+    if policy == "lru":
+        t = cm.solve_che_time(probs, cap)
+        occ = -jnp.expm1(-probs * t)
+    else:
+        t = cm.solve_fifo_tau(probs, cap)
+        occ = probs * t / (1.0 - probs + probs * t)
     assert abs(float(jnp.sum(occ)) - cap) / cap < 1e-3
+    t_ref = _consistency_ref(policy, probs, cap)
+    assert abs(float(t) - t_ref) / t_ref < 1e-3, (float(t), t_ref)
 
 
 def test_hit_rates_bounded_and_ordered():
@@ -63,10 +84,13 @@ def test_uniform_popularity_all_policies_equal():
 # IRM estimators vs actual replay of an IID trace
 # ---------------------------------------------------------------------------
 
+@pytest.mark.parametrize("cap_share", [0.05, 0.15, 0.4])
+@pytest.mark.parametrize("a", [0.9, 1.1, 1.3])
 @pytest.mark.parametrize("policy", ["lru", "fifo", "lfu"])
-def test_irm_estimate_matches_iid_replay(policy):
-    n_pages, cap, n_refs = 2000, 300, 120_000
-    probs = zipf_probs(n_pages, a=1.3, seed=1)
+def test_irm_estimate_matches_iid_replay(policy, a, cap_share):
+    n_pages, n_refs = 2000, 120_000
+    cap = int(cap_share * n_pages)
+    probs = zipf_probs(n_pages, a=a, seed=1)
     rng = np.random.default_rng(2)
     trace = rng.choice(n_pages, size=n_refs, p=probs)
     hits, misses = replay.replay_refs(trace, cap, policy)
@@ -76,6 +100,34 @@ def test_irm_estimate_matches_iid_replay(policy):
     # LFU converges slowly on finite traces (paper §VII-C caveat) — wider tol.
     tol = 0.08 if policy == "lfu" else 0.03
     assert abs(est - actual) < tol, (policy, est, actual)
+
+
+@pytest.mark.parametrize("a", [0.9, 1.1, 1.3])
+@pytest.mark.parametrize("policy", ["lru", "fifo", "lfu"])
+def test_hit_rate_grid_matches_per_capacity_dispatch(policy, a):
+    """One vmapped ``hit_rate_grid`` over a capacity vector equals
+    ``hit_rate`` called once per capacity: the policy fixed point below N
+    distinct pages, the compulsory closed form at and above it (N counts
+    only pages with nonzero mass)."""
+    n_pages, n_refs = 2000, 50_000.0
+    probs = zipf_probs(n_pages, a=a, seed=3)
+    probs[np.random.default_rng(4).choice(n_pages, 200, replace=False)] = 0.0
+    probs /= probs.sum()
+    n_distinct = int(np.sum(probs > 0))
+    caps = np.array([1, 7, 60, 450, 1200, n_distinct - 1, n_distinct,
+                     n_pages + 500], np.int32)
+    counts = np.asarray(probs * n_refs, np.float32)
+    k = len(caps)
+    h_grid, nd = cm.hit_rate_grid(
+        policy, jnp.broadcast_to(jnp.asarray(counts), (k, n_pages)),
+        jnp.full((k,), n_refs, jnp.float32),
+        jnp.full((k,), n_refs, jnp.float32), jnp.asarray(caps))
+    assert np.all(np.asarray(nd) == n_distinct)
+    p32 = jnp.asarray(counts / np.float32(n_refs), jnp.float32)
+    for i, cap in enumerate(caps):
+        h_one = float(cm.hit_rate(policy, int(cap), p32,
+                                  total_requests=n_refs))
+        assert abs(float(h_grid[i]) - h_one) < 1e-5, (policy, cap)
 
 
 def test_compulsory_case_large_capacity():

@@ -1,44 +1,9 @@
-"""Serving tests: engine generation, CAM KV-pool planner vs pool replay."""
-import jax
+"""KV-pool planner tests: CAM's planner vs pool replay."""
 import numpy as np
 import pytest
 
-from repro.configs import ARCHS, reduced
-from repro.models.params import init_params
-from repro.serve.engine import ServeEngine
 from repro.serve.kv_cache import BlockTrace, PagedKVPool
 from repro.serve.planner import RequestMix, block_popularity, plan_kv_pool
-
-
-def test_engine_generates_consistent_shapes():
-    cfg = reduced(ARCHS["starcoder2-3b"])
-    params = init_params(cfg, jax.random.PRNGKey(0))
-    engine = ServeEngine(cfg, params, max_seq=48)
-    prompts = np.random.default_rng(0).integers(
-        0, cfg.vocab_size, size=(2, 8)).astype(np.int32)
-    res = engine.generate(prompts, max_new_tokens=6)
-    assert res.tokens.shape == (2, 14)
-    assert (res.tokens[:, :8] == prompts).all()
-
-
-def test_engine_greedy_matches_full_forward():
-    """First generated token must equal argmax of a full forward pass."""
-    import jax.numpy as jnp
-
-    from repro.distributed.sharding import Recipe, ShardingCtx
-    from repro.models.transformer import transformer_logits
-
-    cfg = reduced(ARCHS["yi-34b"])
-    params = init_params(cfg, jax.random.PRNGKey(1))
-    engine = ServeEngine(cfg, params, max_seq=32)
-    prompts = np.random.default_rng(1).integers(
-        0, cfg.vocab_size, size=(2, 12)).astype(np.int32)
-    res = engine.generate(prompts, max_new_tokens=1)
-    ctx = ShardingCtx(None, Recipe(remat="none"))
-    logits, _, _ = transformer_logits(params, cfg,
-                                      {"tokens": jnp.asarray(prompts)}, ctx)
-    want = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
-    np.testing.assert_array_equal(res.tokens[:, 12], want)
 
 
 # ---------------------------------------------------------------------------
@@ -55,7 +20,21 @@ def _trace_for_mix(mix: RequestMix, block_tokens: int, seed=0):
     return bt.decode_trace(schedule)
 
 
-def test_planner_hit_rate_matches_pool_replay():
+#: Two decode mixes: long private contexts under a moderate shared prefix,
+#: and a long shared system prompt ahead of short private turns.
+MIXES = {
+    "long-context": RequestMix(n_requests=16, shared_prefix=512,
+                               mean_context=1024, decode_steps=12,
+                               kv_bytes_per_token=1024),
+    "long-prompt": RequestMix(n_requests=32, shared_prefix=2048,
+                              mean_context=256, decode_steps=8,
+                              kv_bytes_per_token=1024),
+}
+
+
+@pytest.mark.parametrize("mix_name", sorted(MIXES))
+@pytest.mark.parametrize("block_tokens", [32, 64, 128])
+def test_planner_hit_rate_matches_pool_replay(block_tokens, mix_name):
     """Round-robin decode gives a CYCLIC trace: the IRM (Che) estimate
     overestimates (paper §III-C's caveat transplanted to KV paging), while
     the structural closed form lands on the replay."""
@@ -63,9 +42,7 @@ def test_planner_hit_rate_matches_pool_replay():
     from repro.serve.planner import structural_hit_rate
     import jax.numpy as jnp
 
-    mix = RequestMix(n_requests=16, shared_prefix=512, mean_context=1024,
-                     decode_steps=12, kv_bytes_per_token=1024)
-    block_tokens = 64
+    mix = MIXES[mix_name]
     probs, refs_per_step = block_popularity(mix, block_tokens)
     n_distinct = probs.shape[0]
     pool_blocks = n_distinct // 3          # force real evictions

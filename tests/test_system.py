@@ -1,12 +1,8 @@
 """End-to-end behaviour tests for the whole system.
 
 Covers: the full CAM pipeline (dataset -> index -> workload -> estimate vs
-replay), memory-budget tuning end-to-end, join pipeline, and a short real
-training run with checkpoint-restart through the public launchers.
+replay), memory-budget tuning end-to-end, and the join pipeline.
 """
-import subprocess
-import sys
-
 import numpy as np
 import pytest
 
@@ -78,20 +74,3 @@ def test_join_end_to_end(world):
     st_h = hybrid_join(idx, keys, outer, LAYOUT, cap, n_min=256)
     assert st_h.matches == st_inlj.matches == int(np.isin(outer, keys).sum())
     assert st_h.seconds < st_inlj.seconds     # hotspot workload: big win
-
-
-def test_training_launcher_end_to_end(tmp_path):
-    """Real subprocess through the public CLI: loss decreases, checkpoint
-    restart after an injected failure still completes."""
-    import os
-
-    env = dict(os.environ, PYTHONPATH="src")
-    cmd = [sys.executable, "-m", "repro.launch.train", "--arch",
-           "starcoder2-3b", "--reduced", "--steps", "8", "--batch", "4",
-           "--seq", "64", "--ckpt-dir", str(tmp_path), "--ckpt-every", "4",
-           "--fail-at", "5"]
-    out = subprocess.run(cmd, capture_output=True, text=True, timeout=560,
-                         env=env, cwd="/root/repo")
-    assert out.returncode == 0, out.stderr[-2000:]
-    assert "restarts=1" in out.stdout
-    assert "decreasing=True" in out.stdout
