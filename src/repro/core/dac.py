@@ -5,6 +5,11 @@ distributed in-page offset.  ``*_exact`` variants evaluate the finite sums in
 the lemma proofs directly (used by property tests to certify the closed
 forms), and the RMI variant computes the workload-weighted leaf mixture of
 §V-C.
+
+The RMI mixture is host NumPy float64, not ``jnp``: its inputs (leaf error
+bounds and routing counts) are host arrays, and its one caller wants a
+Python float, so a device version only adds a put of each array and a
+blocking read of one scalar per candidate, and rounds the sum to float32.
 """
 from __future__ import annotations
 
@@ -65,15 +70,17 @@ def expected_dac_one_by_one_exact(eps: int, c_ipp: int) -> float:
 # RMI mixture (§V-C): E[DAC] = sum_j w_j (1 + lambda * eps_j / C_ipp)
 # ---------------------------------------------------------------------------
 
-def expected_dac_rmi(leaf_eps, leaf_weights, c_ipp, strategy: str = "all_at_once"):
+def expected_dac_rmi(leaf_eps, leaf_weights, c_ipp,
+                     strategy: str = "all_at_once") -> float:
     """Workload-weighted mixture over leaf-local error bounds.
 
     ``leaf_eps[j]`` is the empirical max error of leaf j, ``leaf_weights[j]``
-    the probability a query routes to leaf j (estimated from the workload).
+    how many of the workload's queries route to leaf j (any scale: the
+    weights are normalised to probabilities here).  Evaluated in NumPy float64 on the host and returned as a Python float:
+    it does no device work.
     """
     lam = 2.0 if strategy == "all_at_once" else 1.0
-    leaf_eps = jnp.asarray(leaf_eps, jnp.float32)
-    w = jnp.asarray(leaf_weights, jnp.float32)
-    w = w / jnp.maximum(jnp.sum(w), 1e-30)
-    per_leaf = 1.0 + lam * leaf_eps / jnp.asarray(c_ipp, jnp.float32)
-    return jnp.sum(w * per_leaf)
+    leaf_eps = np.asarray(leaf_eps, np.float64)
+    w = np.asarray(leaf_weights, np.float64)
+    w = w / max(float(w.sum()), 1e-30)
+    return float(np.sum(w * (1.0 + lam * leaf_eps / float(c_ipp))))

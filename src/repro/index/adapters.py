@@ -21,6 +21,7 @@ per-leaf Eq. 12 patterns with leaf error bounds quantized up to powers of two
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict
 
 import numpy as np
@@ -232,14 +233,21 @@ class RMIAdapter:
         return {"branch": {"value": self.index.branch, "kind": "fanout",
                            "tunable": True, "grid": DEFAULT_BRANCH_GRID}}
 
+    @functools.cached_property
+    def _leaf_eps_q(self) -> np.ndarray:
+        """``quantize_eps`` of every leaf, once per index: a workload's eps
+        row gathers from it instead of quantizing per query."""
+        return quantize_eps(self.index.leaf_eps)
+
     def point_ref_eps(self, workload: Workload, geom: CamGeometry):
         """Per-query quantized leaf error bounds + E[DAC] (§V-C inputs).
 
         This is what the batched mixed-eps grid kernel
-        (``page_ref.point_page_refs_mixed_eps_grid``) consumes: routing is
-        host-side and cheap, so a whole branch grid can collect every
-        candidate's (eps row, E[DAC]) first and profile them in ONE grouped
-        pass instead of per-branch mixture histograms.
+        (``page_ref.point_page_refs_mixed_eps_grid``) consumes.  Routing,
+        the eps row and the float64 E[DAC] are host NumPy and do no device
+        work, so a whole branch grid can collect every candidate's
+        (eps row, E[DAC]) first and profile them in ONE grouped pass
+        instead of per-branch mixture histograms.
         """
         if workload.kind != POINT or workload.query_keys is None:
             raise UnsupportedWorkloadError(
@@ -252,11 +260,10 @@ class RMIAdapter:
             return hit[1], hit[2]
         index = self.index
         leaf = index.route(workload.query_keys)
-        eps_q = quantize_eps(index.leaf_eps[leaf])
-        weights = np.bincount(leaf, minlength=index.branch).astype(np.float64)
-        weights /= max(weights.sum(), 1.0)
-        e_dac = float(dac_mod.expected_dac_rmi(
-            index.leaf_eps, weights, geom.c_ipp, geom.strategy))
+        eps_q = self._leaf_eps_q[leaf]
+        weights = np.bincount(leaf, minlength=index.branch)
+        e_dac = dac_mod.expected_dac_rmi(index.leaf_eps, weights,
+                                         geom.c_ipp, geom.strategy)
         while len(self._ref_cache) >= self._REF_CACHE_MAX:
             self._ref_cache.pop(next(iter(self._ref_cache)))
         self._ref_cache[key] = (workload.query_keys, eps_q, e_dac)

@@ -1,12 +1,16 @@
 """Learned-index substrate tests: ε guarantee, recursion, RMI windows,
 replay buffers, disk layout."""
+import jax
 import numpy as np
 import pytest
 from _hyp import given, settings, st
 
 from repro.core import replay
+from repro.core.cam import CamGeometry
+from repro.core.workload import Workload
 from repro.data.datasets import make_dataset
 from repro.index import disk_layout, pgm, pla, rmi
+from repro.index.adapters import RMIAdapter, quantize_eps
 
 
 @settings(max_examples=15, deadline=None)
@@ -63,6 +67,47 @@ def test_rmi_error_shrinks_with_branch():
     keys = make_dataset("books", 100_000, seed=6)
     mean_eps = [rmi.build_rmi(keys, b).leaf_eps.mean() for b in (64, 512, 4096)]
     assert mean_eps[0] > mean_eps[1] > mean_eps[2]
+
+
+@pytest.fixture(scope="module")
+def rmi_point_workload():
+    """200k books keys and a skewed 10k-query point workload over them."""
+    keys = make_dataset("books", 200_000, seed=7)
+    rng = np.random.default_rng(7)
+    hot = rng.zipf(1.3, 10_000) % len(keys)
+    return keys, Workload.from_keys(keys, keys[np.sort(hot)])
+
+
+@pytest.mark.parametrize("branch", [1024, 65536])
+def test_rmi_point_ref_eps_does_no_device_transfer(rmi_point_workload, branch):
+    keys, wl = rmi_point_workload
+    adapter = RMIAdapter.build(keys, branch)
+    with jax.transfer_guard("disallow"):
+        eps_q, e_dac = adapter.point_ref_eps(wl, CamGeometry())
+    assert isinstance(e_dac, float)
+    assert isinstance(eps_q, np.ndarray)
+
+
+@pytest.mark.parametrize("strategy,lam", [("all_at_once", 2.0),
+                                          ("one_by_one", 1.0)])
+@pytest.mark.parametrize("branch", [64, 1024, 65536])
+def test_rmi_point_ref_eps_matches_reference(rmi_point_workload, branch,
+                                             strategy, lam):
+    """E[DAC] is the §V-C mixture in float64, and the eps row is the
+    per-query quantized leaf bound, bit for bit."""
+    keys, wl = rmi_point_workload
+    adapter = RMIAdapter.build(keys, branch)
+    geom = CamGeometry(strategy=strategy)
+    eps_q, e_dac = adapter.point_ref_eps(wl, geom)
+
+    leaf = adapter.index.route(wl.query_keys)
+    w = np.bincount(leaf, minlength=branch).astype(np.float64)
+    w /= w.sum()
+    want = np.sum(w * (1.0 + lam * adapter.index.leaf_eps / geom.c_ipp))
+    assert e_dac == pytest.approx(float(want), rel=1e-12)
+    np.testing.assert_array_equal(
+        eps_q, quantize_eps(adapter.index.leaf_eps[leaf]))
+    assert eps_q.dtype == np.int64
 
 
 # ---------------------------------------------------------------------------
